@@ -181,7 +181,8 @@ def run_wallclock(workers: tuple, d: int = 65_536, k: int = 256,
     from repro.core.grab import make_sketch
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(seed)
     rows = [("wallclock_devices", 0, 0, float(n_dev))]
     for w in workers:

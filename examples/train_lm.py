@@ -10,6 +10,7 @@ synthetic corpus -> permuted loader -> fused-GraB microbatch train step ->
 checkpointing -> (optional) resume.
 """
 import argparse
+import os
 
 import jax
 import numpy as np
@@ -21,6 +22,7 @@ from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.optim import adamw, cosine
 from repro.train import LoopConfig, run_training
+from repro.utils.compile_cache import setup_compile_cache
 
 PRESETS = {
     "cpu-smoke": dict(
@@ -99,6 +101,7 @@ def main():
     ap.add_argument("--profile-dir", default="profile_trace",
                     help="directory for the --profile-steps trace")
     args = ap.parse_args()
+    setup_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     p = PRESETS[args.preset]
     cfg = p["model"]

@@ -94,17 +94,15 @@ def local_rank_signs(local_sums: jax.Array, local_zs: jax.Array,
     ``local_zs``:   [dp, k] this step's sketched local gradients.
     Returns (new_sums [dp, k], signs [dp]).
     """
-    from jax.experimental.shard_map import shard_map
-
     def one_rank(s, z):
         # s, z: [1, k] local shard
         dot = jnp.vdot(s, z)
         eps = jnp.where(dot <= 0, jnp.int32(1), jnp.int32(-1))
         return s + eps.astype(jnp.float32) * z, eps[None]
 
-    fn = shard_map(one_rank, mesh=mesh,
-                   in_specs=(P(data_axis, None), P(data_axis, None)),
-                   out_specs=(P(data_axis, None), P(data_axis)))
+    fn = jax.shard_map(one_rank, mesh=mesh,
+                       in_specs=(P(data_axis, None), P(data_axis, None)),
+                       out_specs=(P(data_axis, None), P(data_axis)))
     return fn(local_sums, local_zs)
 
 
@@ -276,8 +274,6 @@ def mesh_pair_signs(s: jax.Array, z_local: jax.Array, mesh,
     XLA scan (``impl="xla"``): this runs under the SPMD partitioner, where a
     pallas_call is opaque.
     """
-    from jax.experimental.shard_map import shard_map
-
     _validate_wire(wire)
     total = mesh.shape[data_axis]
     if key is None:
@@ -293,10 +289,10 @@ def mesh_pair_signs(s: jax.Array, z_local: jax.Array, mesh,
         return coordinated_pair_signs(s_r, zs, kind=kind, c=c, key=key_r,
                                       impl="xla")
 
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(P(), P(data_axis, None), P()),
-                     out_specs=(P(), P()),
-                     check_rep=False)(s, z_local, key)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P(), P(data_axis, None), P()),
+                         out_specs=(P(), P()),
+                         check_vma=False)(s, z_local, key)
 
 
 def mesh_deferred_pair_signs(s: jax.Array, packed: jax.Array, t0: jax.Array,
@@ -327,8 +323,6 @@ def mesh_deferred_pair_signs(s: jax.Array, packed: jax.Array, t0: jax.Array,
     Returns (new_s [k] replicated, signs [T, W] int32 replicated, zeros on
     stash timesteps).
     """
-    from jax.experimental.shard_map import shard_map
-
     total = mesh.shape[data_axis]
 
     def fn(s_r, p_l, t0_r):
@@ -352,7 +346,7 @@ def mesh_deferred_pair_signs(s: jax.Array, packed: jax.Array, t0: jax.Array,
                                   (rows.reshape(n_t * n_w, k), row_live))
         return new_s, eps.reshape(n_t, n_w)
 
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(P(), P(None, data_axis, None), P()),
-                     out_specs=(P(), P()),
-                     check_rep=False)(s, packed, t0)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P(), P(None, data_axis, None), P()),
+                         out_specs=(P(), P()),
+                         check_vma=False)(s, packed, t0)

@@ -40,7 +40,7 @@ def _gla_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scratch, *,
     def _init():
         s_scratch[...] = jnp.zeros_like(s_scratch)
 
-    u = u_ref[0, :]  # [DK]
+    u = u_ref[0, 0, :]  # [DK]
 
     def body(t, _):
         q_t = q_ref[0, t, :]          # [DK]
@@ -61,12 +61,16 @@ def _gla_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scratch, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "post_update"))
-def gla_scan_pallas(q, k, v, w, u, *, interpret: bool = True,
+def gla_scan_pallas(q, k, v, w, u, *, interpret: bool = False,
                     post_update: bool = False):
     """q, k, w: [BH, T, DK]; v: [BH, T, DV]; u: [BH, DK] (zeros = no bonus).
 
     Returns o: [BH, T, DV] f32. The ``ops`` wrapper handles the
     [B, H, ...] <-> [BH, ...] reshapes, padding and u broadcasting.
+
+    ``u`` enters the kernel as ``[BH, 1, DK]`` with ``(1, 1, DK)`` blocks:
+    the TPU compiler refuses a ``(1, DK)`` block of a 2-D ``[BH, DK]`` array
+    (the second-to-last block dim must be a multiple of 8 or the full dim).
     """
     bh, t, dk = q.shape
     dv = v.shape[-1]
@@ -80,12 +84,12 @@ def gla_scan_pallas(q, k, v, w, u, *, interpret: bool = True,
             pl.BlockSpec((1, CHUNK, dk), lambda b, c: (b, c, 0)),  # k
             pl.BlockSpec((1, CHUNK, dv), lambda b, c: (b, c, 0)),  # v
             pl.BlockSpec((1, CHUNK, dk), lambda b, c: (b, c, 0)),  # w
-            pl.BlockSpec((1, dk), lambda b, c: (b, 0)),            # u
+            pl.BlockSpec((1, 1, dk), lambda b, c: (b, 0, 0)),      # u
         ],
         out_specs=pl.BlockSpec((1, CHUNK, dv), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
     )(q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
-      w.astype(jnp.float32), u.astype(jnp.float32))
+      w.astype(jnp.float32), u.astype(jnp.float32).reshape(bh, 1, dk))
     return o

@@ -1,12 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These handle padding/reshaping/dtype so callers (the GraB train step, the
-RWKV6/Hymba blocks) can pass natural shapes. ``interpret`` defaults to True
-off-TPU (this container is CPU-only; on a real TPU pod set
-``REPRO_PALLAS_INTERPRET=0`` or rely on the backend autodetect).
+RWKV6/Hymba blocks) can pass natural shapes. Kernels compile for the TPU
+unless the caller passes ``interpret=True``, which runs the kernel body in
+the Pallas interpreter (how the CPU tests exercise them) and is refused on a
+TPU backend, so a chip run can never time the interpreter by accident.
 """
 from __future__ import annotations
 
+import logging
 import os
 
 import jax
@@ -22,25 +24,28 @@ from repro.kernels.lin_scan import CHUNK, gla_scan_pallas
 from repro.kernels import ref
 
 
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+_log = logging.getLogger(__name__)
+_ref_fallback_logged = False
+
+
+def _check_interpret(interpret: bool) -> None:
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError("interpret=True on a TPU backend: the kernels run "
+                         "compiled on the chip; interpret mode is for CPU "
+                         "tests only")
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def balance_scan(s0: jax.Array, g: jax.Array, interpret: bool | None = None):
+def balance_scan(s0: jax.Array, g: jax.Array, interpret: bool = False):
     """Fused GraB balance scan. s0: [k], g: [m, k] -> (signs [m] int32, s [k]).
 
     Pads m to a TILE_M multiple with zero rows (zero rows get sign +1 and do
     not perturb the sum) and k to a lane multiple.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    _check_interpret(interpret)
     m, k = g.shape
     mp, kp = _round_up(max(m, TILE_M), TILE_M), _round_up(max(k, 128), 128)
     gp = jnp.zeros((mp, kp), jnp.float32).at[:m, :k].set(g.astype(jnp.float32))
@@ -71,7 +76,8 @@ def select_coord_impl(w: int, k: int, chunk_k: int | None = None,
     Returns ("plain", None) for the full-k tiled kernel, ("chunked", ck) for
     the streamed chunked-k kernel, or ("ref", None) when even the chunked
     form's running sum would not fit — the caller falls back to the pure-jnp
-    oracle so the scan stays correct at any k. An explicit ``chunk_k``
+    oracle so the scan stays correct at any k, and says so in one log line
+    the first time a process takes that fallback. An explicit ``chunk_k``
     forces the chunked path unconditionally (tests exercise the chunk
     boundary at small k; the budget only steers the automatic choice).
     """
@@ -89,7 +95,7 @@ def select_coord_impl(w: int, k: int, chunk_k: int | None = None,
 
 
 def coord_balance(s0: jax.Array, z_prev: jax.Array, z_cur: jax.Array | None = None,
-                  interpret: bool | None = None, *, chunk_k: int | None = None,
+                  interpret: bool = False, *, chunk_k: int | None = None,
                   vmem_budget: int | None = None):
     """Fused CD-GraB coordinated pair-balance scan (the W-row sequential
     inner loop of ``core.distributed.coordinated_pair_signs``).
@@ -115,12 +121,17 @@ def coord_balance(s0: jax.Array, z_prev: jax.Array, z_cur: jax.Array | None = No
     """
     if z_cur is None:
         return balance_scan(s0, z_prev, interpret=interpret)
-    if interpret is None:
-        interpret = _default_interpret()
+    _check_interpret(interpret)
     w, k = z_prev.shape
     impl, ck = select_coord_impl(w, k, chunk_k=chunk_k,
                                  vmem_budget=vmem_budget)
     if impl == "ref":
+        global _ref_fallback_logged
+        if not _ref_fallback_logged:
+            _ref_fallback_logged = True
+            _log.warning("coord_balance: W=%d, k=%d exceeds the VMEM budget "
+                         "of both kernels; running the jnp reference scan",
+                         w, k)
         signs, s_out = ref.coord_balance_ref(s0, z_prev, z_cur)
         return signs.astype(jnp.int32), s_out
     if impl == "chunked":
@@ -143,15 +154,14 @@ def coord_balance(s0: jax.Array, z_prev: jax.Array, z_cur: jax.Array | None = No
     return signs[:w].astype(jnp.int32), s_out[:k]
 
 
-def gla_scan(q, k, v, w, u=None, interpret: bool | None = None,
+def gla_scan(q, k, v, w, u=None, interpret: bool = False,
              post_update: bool = False):
     """Gated linear attention. q,k,w: [B,H,T,DK]; v: [B,H,T,DV]; u: [H,DK]|None.
 
     Pads T to a CHUNK multiple (padded steps have k=0, w=1 so the state is
     unchanged and their outputs are dropped). Returns o: [B, H, T, DV] f32.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    _check_interpret(interpret)
     B, H, T, DK = q.shape
     DV = v.shape[-1]
     Tp = _round_up(T, CHUNK)

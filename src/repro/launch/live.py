@@ -75,13 +75,19 @@ def build_live_step(loss_fn: Callable, optimizer, lr_schedule,
         mesh=mesh if cd_grab else None, data_axis=data_axis,
         cd_constraints=cd_cons)
 
-    state = init_train_state(params, optimizer, grab_cfg,
-                             n_workers=n_workers,
-                             n_micro_per_epoch=n_micro_total)
-    s_specs = (cd_grab_state_specs(state, policy, data_axis=data_axis)
-               if cd_grab else state_specs(state, policy))
+    def init(p):
+        return init_train_state(p, optimizer, grab_cfg, n_workers=n_workers,
+                                n_micro_per_epoch=n_micro_total)
+
+    abstract = jax.eval_shape(init, params)
+    s_specs = (cd_grab_state_specs(abstract, policy, data_axis=data_axis)
+               if cd_grab else state_specs(abstract, policy))
     state_shardings = named(mesh, s_specs)
-    state = jax.device_put(state, state_shardings)
+    # the state is built in its sharded layout: made on one device first,
+    # the whole W-stacked f32 stash would have to fit there. Its params are
+    # fresh buffers, so donating the state never deletes the caller's.
+    state = jax.jit(init, in_shardings=(state_shardings.params,),
+                    out_shardings=state_shardings)(params)
 
     # batch leaves are [n_micro, micro, ...]: cd-grab shards the
     # microbatch-stream axis (it regroups to [T, W, ...] in-step, worker

@@ -2,10 +2,15 @@
 
 A FUNCTION, not a module-level constant — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
+
+Every mesh here has ``Auto`` axes: the step pins its intermediates with
+``with_sharding_constraint`` (``launch.sharding``), which refuses the
+``Explicit`` axes ``jax.make_mesh`` defaults to on JAX 0.9.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(model_parallel: int = 16):
@@ -26,7 +31,7 @@ def make_elastic_mesh(model_parallel: int = 16):
     n = jax.device_count()
     assert n % model_parallel == 0, (n, model_parallel)
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

@@ -28,7 +28,8 @@ Telemetry (``repro.obs``) rides the same contract: phase timers
 (loader wait / dispatch / epoch reorder / checkpoint save) are
 ``perf_counter`` spans with profiler annotations, per-epoch ordering-quality
 metrics are computed from the sign buffer's existing once-per-epoch fetch,
-and everything lands in one schema-validated JSONL run log
+the compiled step's device bytes are ``step.*_bytes`` gauges, and
+everything lands in one schema-validated JSONL run log
 (``LoopConfig.metrics_out``) — recording never adds a device→host sync
 (enforced by the transfer-guarded ``tests/test_async_loop.py``).
 """
@@ -39,6 +40,7 @@ import time
 from typing import Any, Callable, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.grab import GrabConfig, grab_epoch_end, make_sketch
@@ -100,6 +102,19 @@ class LoopConfig:
     profile_dir: str = "profile_trace"    # where the captured trace lands
     # --- legacy host-synchronous dispatch (benchmark A/B only) -------------
     sync_transfers: bool = False  # fetch loss + signs every step (blocking)
+
+
+def _record_step_bytes(step_fn, state, batch, reg: MetricsRegistry) -> None:
+    """Compile the jitted step ahead of its first call and record the
+    compiled program's device bytes as ``step.*_bytes`` gauges. The call
+    that follows reuses this executable (the jit cache is shared), so the
+    step is compiled once."""
+    ma = step_fn.lower(state, batch).compile().memory_analysis()
+    if ma is None:                   # backends that do not report it
+        return
+    for part in ("argument", "output", "alias", "temp"):
+        reg.gauge(f"step.{part}_bytes").set(
+            getattr(ma, f"{part}_size_in_bytes"))
 
 
 def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
@@ -229,12 +244,15 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
             shard_policy=loop_cfg.shard_policy,
             cd_constraints=loop_cfg.cd_constraints)
     else:
+        # donated, so old and new state are never both live across a step;
+        # the params are copied in so that donation never deletes the
+        # caller's arrays
         step_fn = jax.jit(build_train_step(
             loss_fn, optimizer, lr_schedule, grab_cfg,
             n_micro_per_epoch=n_micro_total, sketch=sketch,
-            n_workers=n_workers))
-        state = init_train_state(params, optimizer, grab_cfg,
-                                 n_workers=n_workers,
+            n_workers=n_workers), donate_argnums=(0,))
+        state = init_train_state(jax.tree.map(jnp.copy, params), optimizer,
+                                 grab_cfg, n_workers=n_workers,
                                  n_micro_per_epoch=n_micro_total)
 
     start_epoch = 0
@@ -287,6 +305,7 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
         return history[-1]["loss"]
 
     step_timer = reg.timer("phase.step")
+    step_bytes_recorded = False
     for epoch in range(start_epoch, loop_cfg.epochs):
         t0 = time.perf_counter()
         start_s = resume_step if epoch == start_epoch else 0
@@ -300,6 +319,9 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
                 # by the prefetch pool — this is delivery wait only
                 _, batch = next(step_iter)
             with phase("dispatch", reg):
+                if not step_bytes_recorded:
+                    _record_step_bytes(step_fn, state, batch, reg)
+                    step_bytes_recorded = True
                 state, metrics = step_fn(state, batch)
             pending.append((epoch, global_step, metrics["loss"]))
             if loop_cfg.sync_transfers:

@@ -8,12 +8,3 @@ os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
-
-# Property tests prefer the real hypothesis; on images without it, fall back
-# to the deterministic shim so the suite still collects and runs everywhere.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_compat
-    sys.modules["hypothesis"] = _hypothesis_compat
-    sys.modules["hypothesis.strategies"] = _hypothesis_compat.strategies

@@ -96,7 +96,8 @@ def test_coordinated_pair_signs_is_sequential_balancing():
 
 
 def test_mesh_pair_signs_matches_host_scan():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(1)
     zs = jnp.asarray(rng.normal(size=(4, 8)), jnp.float32)
     s0 = jnp.asarray(rng.normal(size=8), jnp.float32)
@@ -331,7 +332,8 @@ def test_cd_grab_sharding_specs():
     specs = cd_grab_state_specs(state, ShardPolicy())
     assert specs.grab.m_acc["mlp"]["wg"] == P("data", None, "model")
     assert specs.grab.s["mlp"]["wg"] == specs.params["mlp"]["wg"]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for spec in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P)):
         NamedSharding(mesh, spec)      # raises on any duplicate-axis spec
 

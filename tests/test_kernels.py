@@ -125,13 +125,17 @@ def test_coord_balance_dtype_promotion(dtype):
 
 
 def test_coord_balance_matches_coordinated_pair_signs_dispatch():
-    """The core-layer dispatcher and the kernel agree on both impls."""
+    """The core-layer dispatcher and the kernel agree on both impls. The
+    dispatcher compiles the kernel for the chip, so on the CPU the test
+    puts it in the TPU interpreter."""
+    from jax.experimental.pallas import tpu as pltpu
     from repro.core.distributed import coordinated_pair_signs
     rng = np.random.default_rng(12)
     zs = jnp.asarray(rng.normal(size=(7, 50)), jnp.float32)
     s0 = jnp.asarray(rng.normal(size=(50,)), jnp.float32)
     s_x, signs_x = coordinated_pair_signs(s0, zs, impl="xla")
-    s_p, signs_p = coordinated_pair_signs(s0, zs, impl="pallas")
+    with pltpu.force_tpu_interpret_mode():
+        s_p, signs_p = coordinated_pair_signs(s0, zs, impl="pallas")
     np.testing.assert_array_equal(np.asarray(signs_x), np.asarray(signs_p))
     np.testing.assert_allclose(np.asarray(s_x), np.asarray(s_p),
                                rtol=1e-5, atol=1e-5)

@@ -64,7 +64,8 @@ _MINI_DRYRUN = textwrap.dedent("""
     from repro.train.step import build_train_step, init_train_state
     from repro.core.grab import GrabConfig
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     set_activation_specs(("data",))
     _, cfg = get_config("{arch}")
     policy = ShardPolicy()

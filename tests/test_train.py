@@ -9,6 +9,7 @@ import pytest
 
 from repro.core.grab import GrabConfig
 from repro.models.paper_models import logreg_init, logreg_loss
+from repro.obs import MetricsRegistry
 from repro.optim import adamw, constant, sgdm
 from repro.train import (CheckpointManager, LoopConfig, build_train_step,
                          init_train_state, run_training)
@@ -65,6 +66,70 @@ def test_loop_converges(ordering):
     state, hist = run_training(loss_fn, params, sgdm(0.9), constant(0.05),
                                ds, 4, cfg)
     assert hist[-1]["loss"] < 0.5 * hist[0]["loss"]
+
+
+@pytest.mark.parametrize("ordering", ["grab", "rr"])
+def test_loop_step_donates_state(ordering):
+    """The single-device step donates its TrainState: the state a hook saw
+    at the end of epoch 0 goes into epoch 1's first step, after which every
+    one of its buffers is deleted. The caller's params are copied in, so
+    donation leaves them alive."""
+    ds, params, loss_fn = _setup()
+    seen = []
+    cfg = LoopConfig(epochs=2, n_micro=8, ordering=ordering, log_every=0)
+    state, _ = run_training(loss_fn, params, sgdm(0.9), constant(0.05), ds, 4,
+                            cfg, hooks=lambda ep, st, h: seen.append(st))
+    # the sign buffer is left out: on the CPU backend the epoch-end
+    # device_get hands back a zero-copy view, and a buffer the host still
+    # references is not donated
+    donated = jax.tree.leaves(seen[0]._replace(signs=None))
+    assert donated and all(x.is_deleted() for x in donated)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(state))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(params))
+
+
+def test_mesh_loop_state_sharded_and_caller_params_kept():
+    """On the mesh path the initial state is built by one jitted init with
+    the state's shardings as outputs: every leaf comes back on the mesh, in
+    fresh buffers, so donating the state leaves the caller's params alive."""
+    from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_elastic_mesh
+
+    ds, params, loss_fn = _setup()
+    mesh = make_elastic_mesh(model_parallel=1)
+    seen = []
+    cfg = LoopConfig(epochs=2, n_micro=8, ordering="cd-grab", workers=2,
+                     mesh=mesh, log_every=0)
+    state, _ = run_training(loss_fn, params, adamw(), constant(0.01), ds, 4,
+                            cfg, grab_cfg=GrabConfig(sketch_dim=8),
+                            hooks=lambda ep, st, h: seen.append(st))
+    assert all(isinstance(x.sharding, NamedSharding)
+               and x.sharding.mesh == mesh for x in jax.tree.leaves(state))
+    assert all(x.is_deleted() for x in jax.tree.leaves(seen[0].params))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(params))
+
+
+def test_loop_records_compiled_step_bytes():
+    """The loop records the compiled step's device bytes as gauges, and the
+    first call reuses that compile: the step is traced once."""
+    ds, params, loss_fn = _setup()
+    traces = []
+
+    def counting_loss(p, mb):
+        traces.append(1)
+        return loss_fn(p, mb)
+
+    reg = MetricsRegistry(print_events=False)
+    cfg = LoopConfig(epochs=2, n_micro=8, ordering="grab", log_every=0,
+                     metrics=reg)
+    run_training(counting_loss, params, sgdm(0.9), constant(0.05), ds, 4, cfg)
+    gauges = reg.summary()["gauges"]
+    state_bytes = 4 * sum(x.size for x in jax.tree.leaves(params))
+    assert gauges["step.argument_bytes"]["last"] > state_bytes
+    assert gauges["step.alias_bytes"]["last"] > 0          # donated
+    assert all(gauges[f"step.{p}_bytes"]["n"] == 1
+               for p in ("argument", "output", "alias", "temp"))
+    assert len(traces) == 1
 
 
 def test_checkpoint_roundtrip_and_resume():
