@@ -9,6 +9,19 @@ streaming-quantile timer under ``phase.<name>``. Timing is
 ``time.perf_counter`` on the host — it measures dispatch/host time, never
 forces a device sync.
 
+The spans the training loop opens (``train/loop.py``): ``loader_wait`` and
+``dispatch`` per step, ``ckpt_save``, and at a GraB epoch boundary
+``epoch_reorder`` with ``sign_fetch``, ``reorder`` and ``rollover`` nested
+in it, then ``epoch_hook`` around the caller's hook. The loop's
+``phase.step`` timer is a plain timer over a whole iteration: on the async
+path it times the enqueue, not the device's step. What runs on the device
+is named by ``jax.named_scope`` instead, in each HLO instruction's
+``op_name``: ``fwd_bwd``, ``grab_balance``, ``grad_accum`` and
+``optimizer`` in the step (``train/step.py``), ``grab_rollover`` in the
+epoch-end rollover. A reader of a device trace attributes an op to the
+first of these names in its ``op_name``; the names are a contract with
+such readers, and changing one changes what they measure.
+
 :class:`ProfileWindow` implements ``--profile-steps A:B``: the run captures
 a JAX profiler trace exactly for global steps ``[A, B)`` and writes it to
 ``log_dir`` (view with TensorBoard or Perfetto). Capturing mid-run, after

@@ -24,18 +24,28 @@ Resume is **exact**: a checkpoint (mid-epoch or boundary) carries the sign
 buffer and GraB state inside ``TrainState``, so the loop continues from the
 exact step it stopped at — no epoch replay, no stale running sum.
 
-Telemetry (``repro.obs``) rides the same contract: phase timers
-(loader wait / dispatch / epoch reorder / checkpoint save) are
-``perf_counter`` spans with profiler annotations, per-epoch ordering-quality
-metrics are computed from the sign buffer's existing once-per-epoch fetch,
-the compiled step's device bytes are ``step.*_bytes`` gauges, and
-everything lands in one schema-validated JSONL run log
-(``LoopConfig.metrics_out``) — recording never adds a device→host sync
-(enforced by the transfer-guarded ``tests/test_async_loop.py``).
+Telemetry (``repro.obs``) rides the same contract: phase timers are
+``perf_counter`` spans with profiler annotations (``obs.trace.phase``):
+``loader_wait``, ``dispatch`` and ``ckpt_save`` per step, and at a GraB
+epoch boundary ``epoch_reorder`` holding ``sign_fetch`` (the one sign
+fetch, which first waits for the epoch's queued steps), ``reorder`` (the
+Algorithm-3 reorder on the host) and ``rollover`` (the dispatch of the
+epoch-end rollover), then ``epoch_hook`` around the caller's ``hooks``.
+``phase.step`` times a whole loop iteration on the host; on the async path
+that is the enqueue, not the device's step. On the device the step's layers
+are named scopes (``train/step.py``) and the rollover runs under
+``grab_rollover``. Per-epoch ordering-quality metrics are computed from the
+sign buffer's existing once-per-epoch fetch, the compiled step's device
+bytes are ``step.*_bytes`` gauges, the GraB state's bytes per chip the
+``grab.state_bytes`` gauge, and everything lands in one schema-validated
+JSONL run log (``LoopConfig.metrics_out``) — recording never adds a
+device→host sync (enforced by the transfer-guarded
+``tests/test_async_loop.py``).
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Callable, Optional
 
@@ -115,6 +125,24 @@ def _record_step_bytes(step_fn, state, batch, reg: MetricsRegistry) -> None:
     for part in ("argument", "output", "alias", "temp"):
         reg.gauge(f"step.{part}_bytes").set(
             getattr(ma, f"{part}_size_in_bytes"))
+
+
+def _record_grab_bytes(grab, reg: MetricsRegistry) -> None:
+    """Record the GraB state's bytes on one chip as the
+    ``grab.state_bytes`` gauge, from each leaf's shard shape: metadata only,
+    no transfer."""
+    reg.gauge("grab.state_bytes").set(sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves(grab)))
+
+
+def grab_rollover(grab, grab_cfg: GrabConfig):
+    """The epoch-end rollover of the GraB state (``grab_epoch_end``), under
+    the ``grab_rollover`` scope. Jitted under this name, so that its program
+    is ``jit_grab_rollover``: most of its work is zero fills and copies that
+    the compiler makes, and those carry no ``op_name``."""
+    with jax.named_scope("grab_rollover"):
+        return grab_epoch_end(grab, grab_cfg)
 
 
 def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
@@ -288,8 +316,10 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
     if use_grab and loop_cfg.mesh is not None:
         epoch_end_kw["out_shardings"] = jax.tree.map(lambda x: x.sharding,
                                                      state.grab)
-    epoch_end_fn = jax.jit(lambda g: grab_epoch_end(g, grab_cfg),
+    epoch_end_fn = jax.jit(grab_rollover, static_argnames="grab_cfg",
                            **epoch_end_kw)
+    if use_grab:
+        _record_grab_bytes(state.grab, reg)
 
     history = []
     pending = []      # (epoch, global_step, device loss) not yet fetched
@@ -345,20 +375,24 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
                     manager.save(global_step, state,
                                  extra={"epoch": epoch,
                                         "order": policy.state_dict()})
-            # dispatch wall time per step (perf_counter, no sync): on the
-            # async path this is host/dispatch latency; sync_transfers=True
-            # makes it the true blocking step time
+            # host wall time of the iteration (perf_counter, no sync): on
+            # the async path this is the enqueue, not the device's step;
+            # sync_transfers=True makes it the true blocking step time
             step_timer.record(time.perf_counter() - ts0)
         # epoch boundary: ONE sign fetch for the whole epoch, then commit the
         # Alg.3 reorder (cd-grab: the coordinated global two-pointer pass)
         # and roll the GraB means
         if use_grab:
             with phase("epoch_reorder", reg):
-                # THE sanctioned sign chokepoint: one fetch per epoch
-                # repro: allow[host-sync]
-                raw_signs = jax.device_get(state.signs)
-                policy.apply_epoch_signs(epoch, raw_signs)
-                state = state._replace(grab=epoch_end_fn(state.grab))
+                with phase("sign_fetch", reg):
+                    # THE sanctioned sign chokepoint: one fetch per epoch
+                    # repro: allow[host-sync]
+                    raw_signs = jax.device_get(state.signs)
+                with phase("reorder", reg):
+                    policy.apply_epoch_signs(epoch, raw_signs)
+                with phase("rollover", reg):
+                    state = state._replace(grab=epoch_end_fn(
+                        state.grab, grab_cfg=grab_cfg))
             # zero-sync ordering quality: numpy over the buffer the reorder
             # already fetched — never an extra transfer
             reg.emit("quality", epoch=epoch,
@@ -370,7 +404,8 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
                              extra={"epoch": epoch + 1,
                                     "order": policy.state_dict()})
         if hooks:
-            hooks(epoch, state, history)
+            with phase("epoch_hook", reg):
+                hooks(epoch, state, history)
         dt = time.perf_counter() - t0
         ep_losses = [h["loss"] for h in history if h["epoch"] == epoch]
         # host floats from flush_losses, no device value  repro: allow[host-sync]

@@ -18,6 +18,16 @@ state pytrees inherit the same specs, its inner product is a per-shard
 partial + scalar psum, and the single optimizer update happens *outside*
 the scan (one fused grad all-reduce per step, overlappable with the last
 microbatch's backward).
+
+The step's layers carry ``jax.named_scope`` names, which reach every HLO
+instruction's ``op_name`` metadata and so a device trace: ``fwd_bwd`` (the
+gradient call, itself a jitted call of that name, the remat recompute
+included), ``grab_balance`` (the balance step, the deferred sign exchange
+and the sign-buffer write), ``grad_accum`` (the f32 accumulator's zeros,
+adds and per-worker mean) and ``optimizer`` (the mean over microbatches,
+the lr schedule and the update). The loop's epoch-end rollover is
+``grab_rollover`` (``train/loop.py``). The names are metadata: the
+compiled step has the same instructions without them.
 """
 from __future__ import annotations
 
@@ -134,40 +144,60 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             return gs._replace(s=s)
         return gs._replace(s=s, m_prev=pin(gs.m_prev), m_acc=pin(gs.m_acc))
 
+    @jax.jit
+    def fwd_bwd(p, mb):
+        # a call of its own, so that ``fwd_bwd`` also names the forward's
+        # loop invariants: differentiating a scan hoists them out of the
+        # model's layer loop with the name stack reset, past any scope
+        return jax.value_and_grad(loss_fn, has_aux=True)(p, mb)
+
     def train_step(state: TrainState, batch):
         params = state.params
-        grad_fn = jax.value_and_grad(lambda p, mb: loss_fn(p, mb), has_aux=True)
 
         def micro(carry, mb):
             acc, grab_state = carry
-            (loss, metrics), grads = grad_fn(params, mb)
-            grads = pin(grads)
+            with jax.named_scope("fwd_bwd"):
+                (loss, metrics), grads = fwd_bwd(params, mb)
+                grads = pin(grads)
             if grab_cfg is not None:
-                grab_state, eps = grab_step(grab_state, grads,
-                                            n_micro_per_epoch, grab_cfg, sketch)
-                grab_state = pin_grab(grab_state)
+                with jax.named_scope("grab_balance"):
+                    grab_state, eps = grab_step(grab_state, grads,
+                                                n_micro_per_epoch, grab_cfg,
+                                                sketch)
+                    grab_state = pin_grab(grab_state)
             else:
                 eps = jnp.int32(0)
-            acc = pin(jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
-                                   acc, grads))
+            with jax.named_scope("grad_accum"):
+                acc = pin(jax.tree.map(
+                    lambda a, g: a + g.astype(jnp.float32), acc, grads))
             return (acc, grab_state), (loss, eps)
 
-        def micro_workers(carry, mb_w):
+        def worker_grads(mb_w):
             # mb_w: [W, micro, ...] — one timestep of W per-worker batches
+            with jax.named_scope("fwd_bwd"):
+                if cdc.slab is not None:
+                    mb_w = cdc.slab(mb_w)
+                (losses, metrics), grads = jax.vmap(
+                    fwd_bwd, in_axes=(None, 0))(params, mb_w)
+                if cdc.grads is not None:
+                    grads = cdc.grads(grads)
+            return losses, grads
+
+        def accumulate_mean(acc, grads):
+            with jax.named_scope("grad_accum"):
+                gmean = pin(jax.tree.map(
+                    lambda g: g.astype(jnp.float32).mean(axis=0), grads))
+                return pin(jax.tree.map(jnp.add, acc, gmean))
+
+        def micro_workers(carry, mb_w):
             acc, grab_state = carry
-            if cdc.slab is not None:
-                mb_w = cdc.slab(mb_w)
-            (losses, metrics), grads = jax.vmap(
-                grad_fn, in_axes=(None, 0))(params, mb_w)
-            if cdc.grads is not None:
-                grads = cdc.grads(grads)
-            grab_state, eps = grab_step_workers(grab_state, grads,
-                                                grab_cfg, sketch,
-                                                mesh=mesh, data_axis=data_axis)
-            grab_state = pin_grab(grab_state)
-            gmean = pin(jax.tree.map(
-                lambda g: g.astype(jnp.float32).mean(axis=0), grads))
-            acc = pin(jax.tree.map(jnp.add, acc, gmean))
+            losses, grads = worker_grads(mb_w)
+            with jax.named_scope("grab_balance"):
+                grab_state, eps = grab_step_workers(
+                    grab_state, grads, grab_cfg, sketch, mesh=mesh,
+                    data_axis=data_axis)
+                grab_state = pin_grab(grab_state)
+            acc = accumulate_mean(acc, grads)
             return (acc, grab_state), (losses.mean(), eps)
 
         def micro_workers_collect(carry, mb_w):
@@ -175,21 +205,16 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             # dataflow only stashes this timestep's packed int8 row — no
             # collective inside the scan
             acc, grab_state = carry
-            if cdc.slab is not None:
-                mb_w = cdc.slab(mb_w)
-            (losses, metrics), grads = jax.vmap(
-                grad_fn, in_axes=(None, 0))(params, mb_w)
-            if cdc.grads is not None:
-                grads = cdc.grads(grads)
-            grab_state, packed = grab_step_workers_collect(
-                grab_state, grads, grab_cfg, sketch)
-            grab_state = pin_grab(grab_state)
-            gmean = pin(jax.tree.map(
-                lambda g: g.astype(jnp.float32).mean(axis=0), grads))
-            acc = pin(jax.tree.map(jnp.add, acc, gmean))
+            losses, grads = worker_grads(mb_w)
+            with jax.named_scope("grab_balance"):
+                grab_state, packed = grab_step_workers_collect(
+                    grab_state, grads, grab_cfg, sketch)
+                grab_state = pin_grab(grab_state)
+            acc = accumulate_mean(acc, grads)
             return (acc, grab_state), (losses.mean(), packed)
 
-        acc0 = pin(tree_zeros_like(params, jnp.float32))
+        with jax.named_scope("grad_accum"):
+            acc0 = pin(tree_zeros_like(params, jnp.float32))
         if n_workers > 1 and deferred:
             from repro.core.distributed import mesh_deferred_pair_signs
             batch_w = jax.tree.map(
@@ -200,9 +225,10 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             # one batched exchange for the whole step's [T, W, k+4] stash;
             # independent of the grad-mean/optimizer chain below, so the
             # compiler overlaps the gather with the epilogue
-            new_s, signs = mesh_deferred_pair_signs(
-                grab_state.s, packed, state.grab.t, mesh, data_axis,
-                hier_group=grab_cfg.sign_hier)
+            with jax.named_scope("grab_balance"):
+                new_s, signs = mesh_deferred_pair_signs(
+                    grab_state.s, packed, state.grab.t, mesh, data_axis,
+                    hier_group=grab_cfg.sign_hier)
             grab_state = grab_state._replace(s=new_s)
         elif n_workers > 1:
             batch_w = jax.tree.map(
@@ -214,20 +240,22 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             (acc, grab_state), (losses, signs) = jax.lax.scan(
                 micro, (acc0, pin_grab(state.grab)), batch)
 
-        n_steps = losses.shape[0]
-        grads = jax.tree.map(lambda a: a / n_steps, acc)
-        lr = lr_schedule(state.step)
-        opt_state, params = optimizer.update(state.opt, grads, params, lr)
+        with jax.named_scope("optimizer"):
+            n_steps = losses.shape[0]
+            grads = jax.tree.map(lambda a: a / n_steps, acc)
+            lr = lr_schedule(state.step)
+            opt_state, params = optimizer.update(state.opt, grads, params, lr)
         new_signs = state.signs
         if state.signs is not None and grab_cfg is not None:
             # device-resident sign buffer: append this step's rows at the
             # GraB clock (grab.t before the scan = timesteps already done
             # this epoch), so the buffer is epoch-positional and a resumed
             # step overwrites exactly the rows it would have produced
-            rows = signs if n_workers > 1 else signs[:, None]
-            new_signs = jax.lax.dynamic_update_slice(
-                state.signs, rows.astype(jnp.int8),
-                (state.grab.t, jnp.int32(0)))
+            with jax.named_scope("grab_balance"):
+                rows = signs if n_workers > 1 else signs[:, None]
+                new_signs = jax.lax.dynamic_update_slice(
+                    state.signs, rows.astype(jnp.int8),
+                    (state.grab.t, jnp.int32(0)))
         new_state = TrainState(params=params, opt=opt_state, grab=grab_state,
                                step=state.step + 1, signs=new_signs)
         metrics = {"loss": losses.mean(), "signs": signs, "lr": lr}

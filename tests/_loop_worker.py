@@ -54,6 +54,12 @@ def main():
     metrics_out = os.environ.get("REPRO_TEST_METRICS")
     if metrics_out:
         sys.argv += ["--metrics-out", metrics_out]
+    # a no-op epoch hook, so that the loop's epoch_hook span runs inside
+    # the guard too
+    import repro.train
+    run_training = repro.train.run_training
+    repro.train.run_training = lambda *a, **kw: run_training(
+        *a, hooks=lambda epoch, state, history: None, **kw)
     import runpy
     with jax.transfer_guard_device_to_host("disallow"):
         runpy.run_path(os.path.join(_REPO, "examples", "train_lm.py"),

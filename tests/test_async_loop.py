@@ -72,12 +72,20 @@ def test_async_loop_fetches_signs_once_per_epoch(tmp_path):
 
     epochs = records_of_kind(records, "epoch")
     assert len(epochs) == rec["epochs"], [r["kind"] for r in records]
-    for ep in epochs:
+    for i, ep in enumerate(epochs):
         timers = ep["timers"]
         # per-step timer quantiles + every instrumented phase showed up
         for t in ("phase.step", "phase.dispatch", "phase.loader_wait",
                   "phase.epoch_reorder"):
             assert t in timers, (t, sorted(timers))
+        # the epoch boundary's sub-spans and the hook's span, once per
+        # epoch (timers are cumulative over the run)
+        for t in ("phase.sign_fetch", "phase.reorder", "phase.rollover",
+                  "phase.epoch_hook"):
+            assert timers[t]["count"] == i + 1, (t, timers.get(t))
+        # the GraB state's bytes per chip, set once at set-up
+        assert ep["gauges"]["grab.state_bytes"]["last"] > 0
+        assert ep["gauges"]["grab.state_bytes"]["n"] == 1
         for q in ("p50_s", "p95_s", "p99_s"):
             assert timers["phase.step"][q] >= 0.0
         # loader health gauges ride the same record

@@ -132,6 +132,26 @@ def test_loop_records_compiled_step_bytes():
     assert len(traces) == 1
 
 
+@pytest.mark.parametrize("ordering", ["grab", "rr"])
+def test_loop_records_grab_state_bytes(ordering):
+    """The loop records the GraB state's bytes on one chip as the
+    ``grab.state_bytes`` gauge, once, from shapes alone; rr has no GraB
+    state and no gauge."""
+    ds, params, loss_fn = _setup()
+    reg = MetricsRegistry(print_events=False)
+    cfg = LoopConfig(epochs=1, n_micro=8, ordering=ordering, log_every=0,
+                     metrics=reg)
+    state, _ = run_training(loss_fn, params, sgdm(0.9), constant(0.05), ds,
+                            4, cfg)
+    gauges = reg.summary()["gauges"]
+    if ordering == "rr":
+        assert "grab.state_bytes" not in gauges
+        return
+    assert gauges["grab.state_bytes"]["n"] == 1
+    assert gauges["grab.state_bytes"]["last"] == sum(
+        x.nbytes for x in jax.tree.leaves(state.grab))
+
+
 def test_checkpoint_roundtrip_and_resume():
     ds, params, loss_fn = _setup()
     with tempfile.TemporaryDirectory() as d:
