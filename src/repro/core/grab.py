@@ -11,6 +11,14 @@ balancer, update the running signed sum ``s`` and the fresh-mean accumulator
 the gradient's PartitionSpecs, so the balancing inner product lowers to
 per-shard partial dots + one scalar all-reduce.
 
+``grab_step`` is two halves that callers may also run apart:
+:func:`grab_balance_step` (center, sign, update ``s``, advance ``t``) and
+:func:`grab_fold_mean` (``m_acc + grad_sum / n_per_epoch``). The fresh mean
+is a plain sum, so it need not be folded per gradient: the train step
+balances every microbatch but folds its f32 gradient sum into ``m_acc`` once
+per optimizer step, after the microbatch scan, which keeps ``m_acc`` out of
+the scan's per-microbatch reads and writes.
+
 Sketch mode (beyond the paper) keeps ``s`` only for a fixed coordinate
 subsample of the gradient (``k`` entries), cutting balance state and the
 sequential-scan bandwidth from O(d) to O(k). The Pallas kernel in
@@ -66,7 +74,8 @@ class GrabConfig:
 class GrabState(NamedTuple):
     s: Any            # running signed sum (pytree, or [k] vector in sketch mode)
     m_prev: Any       # stale mean from previous epoch (pytree)
-    m_acc: Any        # fresh mean accumulator (pytree)
+    m_acc: Any        # fresh mean accumulator (pytree): the epoch's gradients
+                      # so far over n_per_epoch, folded in once per train step
     t: jax.Array      # step within epoch
     key: jax.Array    # PRNG (alweiss only)
 
@@ -177,6 +186,19 @@ def grab_step(state: GrabState, grad, n_per_epoch: int, cfg: GrabConfig,
     odd step and the host expands it)."""
     if cfg.pair_balance:
         return _grab_step_pair(state, grad, cfg, sketch)
+    state, eps = grab_balance_step(state, grad, cfg, sketch)
+    return state._replace(m_acc=grab_fold_mean(state.m_acc, grad,
+                                               n_per_epoch)), eps
+
+
+def grab_balance_step(state: GrabState, grad, cfg: GrabConfig,
+                      sketch: Optional[Sketch] = None):
+    """The balance half of :func:`grab_step`: center ``grad`` with the stale
+    mean, pick its sign, add the signed centered gradient to ``s`` and
+    advance ``t``. ``m_acc`` is left as it is (it may be None); the caller
+    folds the gradient in with :func:`grab_fold_mean`. Returns (new_state,
+    eps in {-1,+1})."""
+    assert not cfg.pair_balance, "pair balancing stashes in m_acc: grab_step"
     g32 = jax.tree.map(lambda x: x.astype(jnp.float32), grad)
     centered = jax.tree.map(jnp.subtract, g32, state.m_prev)
 
@@ -198,10 +220,17 @@ def grab_step(state: GrabState, grad, n_per_epoch: int, cfg: GrabConfig,
                                            c=cfg.alweiss_c, key=sub)
         else:
             new_s, eps = tree_balance_step(state.s, centered)
+    return state._replace(s=new_s, t=state.t + 1, key=key), eps
 
-    m_acc = jax.tree.map(lambda a, g: a + g / n_per_epoch, state.m_acc, g32)
-    return GrabState(s=new_s, m_prev=state.m_prev, m_acc=m_acc,
-                     t=state.t + 1, key=key), eps
+
+def grab_fold_mean(m_acc, grad_sum, n_per_epoch: int):
+    """The fresh-mean half of :func:`grab_step`: ``m_acc + grad_sum /
+    n_per_epoch`` in f32. ``grad_sum`` is one gradient, or the sum of
+    several (a train step's f32 accumulator): the epoch's mean is the same
+    sum either way, up to the order of the f32 additions."""
+    return jax.tree.map(
+        lambda a, g: a + g.astype(jnp.float32) / n_per_epoch, m_acc,
+        grad_sum)
 
 
 def _grab_step_pair(state: GrabState, grad, cfg: GrabConfig,

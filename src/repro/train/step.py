@@ -5,11 +5,16 @@ and scans over the microbatch axis:
 
     for t in range(n_micro):                       # lax.scan
         g_t   = grad(loss)(params, micro_t)        # needed for accumulation anyway
-        state, eps_t = grab_step(state, g_t)       # O(d) dot + sign + axpy
+        state, eps_t = grab_balance_step(state, g_t)  # dot + sign + axpy
         acc  += g_t
+    state.m_acc = grab_fold_mean(state.m_acc, acc)    # once per step
 
 so GraB's ordering signal costs **zero extra gradient computations** — the
 paper's §6 gradient-accumulation workaround as a first-class systems feature.
+The fresh mean ``m_acc`` is a sum the accumulator ``acc`` already holds, so
+it stays out of the scan: folding it per microbatch would read and write one
+more f32 tree every microbatch instead of once a step. (Pair balancing keeps
+its pair stash in ``m_acc`` and runs ``grab_step`` whole inside the scan.)
 The per-microbatch signs come back to the host, which reorders the global
 microbatch permutation for the next epoch (Algorithm 3 two-pointer).
 
@@ -22,12 +27,12 @@ microbatch's backward).
 The step's layers carry ``jax.named_scope`` names, which reach every HLO
 instruction's ``op_name`` metadata and so a device trace: ``fwd_bwd`` (the
 gradient call, itself a jitted call of that name, the remat recompute
-included), ``grab_balance`` (the balance step, the deferred sign exchange
-and the sign-buffer write), ``grad_accum`` (the f32 accumulator's zeros,
-adds and per-worker mean) and ``optimizer`` (the mean over microbatches,
-the lr schedule and the update). The loop's epoch-end rollover is
-``grab_rollover`` (``train/loop.py``). The names are metadata: the
-compiled step has the same instructions without them.
+included), ``grab_balance`` (the balance step, the fresh-mean fold, the
+deferred sign exchange and the sign-buffer write), ``grad_accum`` (the f32
+accumulator's zeros, adds and per-worker mean) and ``optimizer`` (the mean
+over microbatches, the lr schedule and the update). The loop's epoch-end
+rollover is ``grab_rollover`` (``train/loop.py``). The names are metadata:
+the compiled step has the same instructions without them.
 """
 from __future__ import annotations
 
@@ -37,7 +42,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.grab import (GrabConfig, Sketch, grab_step, grab_step_workers,
+from repro.core.grab import (GrabConfig, Sketch, grab_balance_step,
+                             grab_fold_mean, grab_step, grab_step_workers,
                              grab_step_workers_collect, init_grab_state,
                              init_parallel_grab_state, init_sign_buffer)
 from repro.optim.optimizers import Optimizer
@@ -132,6 +138,10 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 and grab_cfg.sign_wire == "int8"
                 and grab_cfg.balancer == "deterministic"
                 and grab_cfg.sketch_dim > 0)
+    # Single-worker GraB without pair balancing folds the fresh mean once per
+    # step from the accumulator, after the scan; m_acc is not in the carry.
+    fold_once = (n_workers == 1 and grab_cfg is not None
+                 and not grab_cfg.pair_balance)
 
     def pin_grab(gs):
         if gs is None or grab_cfg is None:
@@ -142,7 +152,8 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 return gs._replace(s=s, m_prev=cdc.stash(gs.m_prev),
                                    m_acc=cdc.stash(gs.m_acc))
             return gs._replace(s=s)
-        return gs._replace(s=s, m_prev=pin(gs.m_prev), m_acc=pin(gs.m_acc))
+        m_acc = None if gs.m_acc is None else pin(gs.m_acc)
+        return gs._replace(s=s, m_prev=pin(gs.m_prev), m_acc=m_acc)
 
     @jax.jit
     def fwd_bwd(p, mb):
@@ -161,9 +172,13 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 grads = pin(grads)
             if grab_cfg is not None:
                 with jax.named_scope("grab_balance"):
-                    grab_state, eps = grab_step(grab_state, grads,
-                                                n_micro_per_epoch, grab_cfg,
-                                                sketch)
+                    if fold_once:
+                        grab_state, eps = grab_balance_step(
+                            grab_state, grads, grab_cfg, sketch)
+                    else:
+                        grab_state, eps = grab_step(
+                            grab_state, grads, n_micro_per_epoch, grab_cfg,
+                            sketch)
                     grab_state = pin_grab(grab_state)
             else:
                 eps = jnp.int32(0)
@@ -237,8 +252,14 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             (acc, grab_state), (losses, signs) = jax.lax.scan(
                 micro_workers, (acc0, pin_grab(state.grab)), batch_w)
         else:
+            carry = (state.grab._replace(m_acc=None) if fold_once
+                     else state.grab)
             (acc, grab_state), (losses, signs) = jax.lax.scan(
-                micro, (acc0, pin_grab(state.grab)), batch)
+                micro, (acc0, pin_grab(carry)), batch)
+            if fold_once:
+                with jax.named_scope("grab_balance"):
+                    grab_state = grab_state._replace(m_acc=pin(grab_fold_mean(
+                        state.grab.m_acc, acc, n_micro_per_epoch)))
 
         with jax.named_scope("optimizer"):
             n_steps = losses.shape[0]

@@ -12,6 +12,7 @@ Instructions that the compiler inserts itself (copies, layout changes, the
 CPU's rewrites of reductions) carry no ``op_name`` at all; the program
 cannot name them, and a chip trace reads their time as unscoped.
 """
+import collections
 import re
 
 import jax
@@ -57,20 +58,24 @@ def _bytes(shape: str) -> int:
 
 def _instructions(hlo: str) -> list:
     """Every instruction as a dict: its computation, whether that is a
-    fusion's body, name, shape, opcode and ``op_name``."""
-    out, comp = [], None
+    fusion's body or the entry, name, shape, opcode, ``op_name``, the
+    computations it calls and, for a loop, its condition and body."""
+    out, comp, entry = [], None, False
     for line in hlo.splitlines():
         m = _COMP.match(line)
         if m:
-            comp = m.group(1)
+            comp, entry = m.group(1), line.startswith("ENTRY")
             continue
         m = _INSTR.match(line)
         if m and comp is not None:
             op = re.search(r'op_name="([^"]*)"', line)
-            out.append({"comp": comp, "name": m.group(1),
+            out.append({"comp": comp, "entry": entry, "name": m.group(1),
                         "shape": m.group(2), "opcode": m.group(3),
                         "op_name": op.group(1) if op else None,
-                        "calls": re.findall(r"calls=%?([\w.\-]+)", line)})
+                        "calls": re.findall(
+                            r"(?:calls|to_apply)=%?([\w.\-]+)", line),
+                        "loops": re.findall(
+                            r"(?:condition|body)=%?([\w.\-]+)", line)})
     fused = {c for i in out if i["opcode"] == "fusion" for c in i["calls"]}
     for i in out:
         i["fused"] = i["comp"] in fused
@@ -95,7 +100,8 @@ def step_hlo(request):
     state = init_train_state(params, opt, grab_cfg, n_micro_per_epoch=8)
     batch = {k: jnp.zeros((4, 2, 16), jnp.int32) for k in ("tokens", "labels")}
     hlo = step.lower(state, batch).compile().as_text()
-    leaf_shapes = {tuple(x.shape) for x in jax.tree.leaves(params)}
+    leaf_shapes = collections.Counter(
+        tuple(x.shape) for x in jax.tree.leaves(params))
     smallest = min(x.size * 4 for x in jax.tree.leaves(params))
     return request.param, _instructions(hlo), leaf_shapes, smallest
 
@@ -133,6 +139,46 @@ def test_balance_falls_under_grab_balance(step_hlo):
     adds = [_shape_dims(i["shape"]) for i in bal if i["opcode"] == "add"]
     for shape in leaf_shapes:
         assert adds.count(("f32", shape)) >= 2, (shape, adds)
+
+
+def _closure(ins, roots, loops: bool) -> set:
+    """The computations ``roots`` reach through calls and, if ``loops``,
+    through loop conditions and bodies."""
+    edges = collections.defaultdict(list)
+    for i in ins:
+        edges[i["comp"]] += i["calls"] + (i["loops"] if loops else [])
+    seen, todo = set(), list(roots)
+    while todo:
+        comp = todo.pop()
+        if comp not in seen:
+            seen.add(comp)
+            todo += edges[comp]
+    return seen
+
+
+def test_fresh_mean_folds_once_after_the_microbatch_loop(step_hlo):
+    """GraB's fresh mean ``m_acc`` is folded from the step's f32 gradient
+    sum once, after the microbatch loop: inside the loop each parameter
+    leaf has one f32 add under ``grab_balance`` (the running sum ``s``) and
+    none of ``m_acc``; outside it, exactly one more (the fold)."""
+    arm, ins, leaf_shapes, _ = step_hlo
+    if arm == "rr":
+        return
+    entry = {i["comp"] for i in ins if i["entry"]}
+    bodies = [c for i in ins if i["entry"] for c in i["loops"]]
+    assert bodies                            # the microbatch loop
+    inside = _closure(ins, bodies, loops=True)
+    outside = _closure(ins, entry, loops=False)
+
+    def balance_adds(comps):
+        return collections.Counter(
+            _shape_dims(i["shape"])[1] for i in ins
+            if i["comp"] in comps and i["scope"] == "grab_balance"
+            and i["opcode"] == "add" and _shape_dims(i["shape"])[0] == "f32"
+            and _shape_dims(i["shape"])[1] in leaf_shapes)
+
+    assert balance_adds(inside) == leaf_shapes
+    assert balance_adds(outside) == leaf_shapes
 
 
 def test_adamw_moments_fall_under_optimizer(step_hlo):

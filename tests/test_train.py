@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.grab import GrabConfig
+from repro.core.grab import GrabConfig, grab_epoch_end, grab_step
 from repro.models.paper_models import logreg_init, logreg_loss
 from repro.obs import MetricsRegistry
 from repro.optim import adamw, constant, sgdm
@@ -46,6 +46,67 @@ def test_train_step_signs_and_loss():
     assert set(np.unique(np.asarray(metrics["signs"]))) <= {-1, 1}
     assert np.isfinite(float(metrics["loss"]))
     assert int(state.step) == 1
+
+
+def _steps_against_grab_step(cfg, n_steps=2, n_micro=8):
+    """Run ``n_steps`` built train steps of an epoch and, beside them,
+    ``grab_step`` on each microbatch's gradient in turn, from the same
+    state. Returns both GraB states and both sign sequences."""
+    ds, params, loss_fn = _setup()
+    n_per_epoch = n_steps * n_micro
+    step = jax.jit(build_train_step(loss_fn, sgdm(0.9), constant(0.05), cfg,
+                                    n_micro_per_epoch=n_per_epoch))
+    grad = jax.jit(jax.grad(lambda p, mb: loss_fn(p, mb)[0]))
+    ref_step = jax.jit(lambda st, g: grab_step(st, g, n_per_epoch, cfg))
+    state = init_train_state(params, sgdm(0.9), cfg)
+    ref, signs, ref_signs = state.grab, [], []
+    for k in range(n_steps):
+        rows = slice(4 * n_micro * k, 4 * n_micro * (k + 1))
+        batch = {"x": ds.x[rows].reshape(n_micro, 4, -1),
+                 "y": ds.y[rows].reshape(n_micro, 4)}
+        for t in range(n_micro):
+            g = grad(state.params, jax.tree.map(lambda a: a[t], batch))
+            ref, eps = ref_step(ref, g)
+            ref_signs.append(int(eps))
+        state, metrics = step(state, batch)
+        signs += np.asarray(metrics["signs"]).tolist()
+    return state.grab, ref, signs, ref_signs
+
+
+def test_step_folds_fresh_mean_once_like_grab_step():
+    """The built step balances every microbatch but folds its gradient sum
+    into the fresh mean once per step; two steps of an epoch match
+    ``grab_step`` applied microbatch by microbatch. The running sum and the
+    signs are the same bit for bit; the fresh mean, and the stale mean the
+    epoch's end makes of it, differ only by the order of f32 additions."""
+    cfg = GrabConfig()
+    got, ref, signs, ref_signs = _steps_against_grab_step(cfg)
+    assert signs == ref_signs and set(signs) == {-1, 1}
+    assert int(got.t) == int(ref.t) == 16
+    for a, b in zip(jax.tree.leaves(got.s), jax.tree.leaves(ref.s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree.leaves(got.m_acc), jax.tree.leaves(ref.m_acc)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+    got, ref = grab_epoch_end(got, cfg), grab_epoch_end(ref, cfg)
+    for a, b in zip(jax.tree.leaves(got.m_prev), jax.tree.leaves(ref.m_prev)):
+        assert np.abs(np.asarray(a)).max() > 0
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+def test_pair_balance_step_keeps_its_stash_in_m_acc():
+    """Pair balancing runs ``grab_step`` whole in the step: ``m_acc`` is the
+    pair stash, which after a step of eight microbatches holds the seventh's
+    gradient, as ``grab_step`` microbatch by microbatch leaves it."""
+    cfg = GrabConfig(pair_balance=True)
+    got, ref, signs, ref_signs = _steps_against_grab_step(cfg)
+    assert signs == ref_signs and set(signs[1::2]) <= {-1, 1}
+    assert set(signs[0::2]) == {0}
+    for tree in ("s", "m_acc"):
+        for a, b in zip(jax.tree.leaves(getattr(got, tree)),
+                        jax.tree.leaves(getattr(ref, tree))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert all(np.abs(np.asarray(x)).max() > 0
+               for x in jax.tree.leaves(got.m_acc))
 
 
 def test_grab_state_none_for_rr():
