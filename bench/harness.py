@@ -13,6 +13,12 @@ What the window ran is checked against ``reference.py``: the first
 window then drives) and the order of epochs 0 and 1. The probes read the
 loop's state after the first and the last of those steps, in set-up; the
 reference runs after the window, once the program's state is freed.
+
+A configuration with a ``mesh`` runs on a mesh of the cell's chips, which
+``run_training`` is given as ``LoopConfig.mesh``: the traffic's CD-GraB
+workers, and the sign wire its ``grab`` block sets, then take the
+program's sharded path, and the reference's CD-GraB mode puts one worker
+to a chip.
 """
 from __future__ import annotations
 
@@ -31,10 +37,13 @@ import data as bench_data
 import flops
 import reference
 import trace_reduce
+import trace_scopes
 from layout import BENCH_DIR, Layout
 
 WINDOW_SPAN = "bench_window"
-SPANS = ("loader_wait", "dispatch", "epoch_reorder", "ckpt_save")
+SPANS = ("loader_wait", "dispatch", "epoch_reorder", "ckpt_save",
+         "sign_fetch", "reorder", "rollover", "epoch_hook")
+ORDERINGS = ("grab", "cd-grab", "rr")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -84,7 +93,8 @@ def compare(prog: dict, ref: dict, grab: bool):
     reference's, and for each per-leaf number the index of its worst leaf.
     Leaves whose first reference gradient is under a thousandth of the
     median leaf's move by round-off alone and are left out of the
-    parameters' change."""
+    parameters' change. Where the reference gives its own CD-GraB signs,
+    ``sign_mismatch`` counts the program's that differ from them."""
     live = ref["grad_raw"] >= 1e-3 * np.median(ref["grad_raw"])
     out, worst = {}, {}
     out["loss_gap"] = float(np.max(np.abs(prog["losses"] - ref["losses"])
@@ -93,7 +103,12 @@ def compare(prog: dict, ref: dict, grab: bool):
     out["update_gap"], worst["update_gap"] = worst_gap(
         prog["update"], ref["update"], live)
     if grab:
-        out["sum_gap"], worst["sum_gap"] = worst_gap(prog["sum"], ref["sum"])
+        out["sum_gap"], i = worst_gap(prog["sum"], ref["sum"])
+        if len(ref["sum"]) > 1:          # a CD-GraB sketch sum is one vector
+            worst["sum_gap"] = i
+    if "signs" in ref:
+        out["sign_mismatch"] = float(np.sum(
+            np.asarray(prog["signs"]) != np.asarray(ref["signs"])))
     return out, worst
 
 
@@ -181,6 +196,31 @@ def _make_probe(on_dispatch, last: int):
     return Probe(print_events=False)
 
 
+def read_trace(trace_dir: str) -> dict:
+    """``trace_reduce.reduce_events`` of the trace in ``trace_dir`` inside
+    the window, with the device seconds of each named scope of the step
+    (``scope_s``) and of the rest (``unscoped_s``) by
+    ``trace_scopes.reduce_events``, whose top ops, named
+    ``<scope>/<op>``, and idle gaps, labelled ``parent/child``, make the
+    breakdown. The trace is read once."""
+    ev = trace_scopes.read_events(trace_reduce.find_trace(trace_dir),
+                                  SPANS + (WINDOW_SPAN,))
+    out = trace_reduce.reduce_events(ev, WINDOW_SPAN)
+    scopes = trace_scopes.reduce_events(ev, WINDOW_SPAN)
+    for k in ("scope_s", "unscoped_s", "device_ops", "idle_gaps"):
+        out[k] = scopes[k]
+    return out
+
+
+def make_mesh(devices, shape: dict):
+    """The configuration's mesh (axis name -> size) over ``devices``, with
+    ``Auto`` axes, as the program's launcher builds its meshes."""
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.array(devices).reshape(tuple(shape.values())),
+                tuple(shape), axis_types=(AxisType.Auto,) * len(shape))
+
+
 def _trace_options():
     import jax
 
@@ -225,7 +265,12 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     model = get_config(cfg["program"]["arch"])[0].with_(
         **cfg["program"]["overrides"])
     _check_model(model, cfg)
-    grab = traffic["ordering"] == "grab"
+    ordering = traffic["ordering"]
+    if ordering not in ORDERINGS:
+        raise ValueError(f"unknown ordering {ordering!r}")
+    grab = ordering != "rr"
+    workers = traffic.get("workers", 1)
+    mesh = make_mesh(devices, cfg["mesh"]) if cfg.get("mesh") else None
     seq, micro = traffic["seq_len"], traffic["micro"]
     n_micro = traffic["n_micro"]
     spe = traffic["steps_per_epoch"]
@@ -260,8 +305,17 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                                       np.float64) / (1.0 - hp["b1"])
         if n == n_ref:
             st = loc["state"]
+            if mesh is None:
+                start = init(key)
+            else:
+                # the step's temporaries go first; the weights come back
+                # in the step's own shardings, never whole on one chip
+                jax.block_until_ready(st)
+                start = jax.device_put(params, jax.tree.map(
+                    lambda x: x.sharding, st.params))
             prog["update"] = np.asarray(jax.device_get(
-                diff_norms(st.params, init(key))), np.float64)
+                diff_norms(st.params, start)), np.float64)
+            del start
             if grab:
                 prog["sum"] = np.asarray(jax.device_get(
                     leaf_norms(st.grab.s)), np.float64)
@@ -318,8 +372,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     opt = adamw(b1=hp["b1"], b2=hp["b2"], eps=hp["eps"],
                 weight_decay=hp["weight_decay"], clip_norm=hp["clip_norm"])
     loop_cfg = LoopConfig(
-        epochs=2 ** 31 - 1, n_micro=n_micro, ordering=traffic["ordering"],
-        log_every=0, seed=seed_np, metrics=reg,
+        epochs=2 ** 31 - 1, n_micro=n_micro, ordering=ordering,
+        workers=workers, mesh=mesh, log_every=0, seed=seed_np, metrics=reg,
         loader_workers=traffic["loader"]["workers"],
         loader_window=traffic["loader"]["window"],
         loader_buffer=traffic["loader"]["buffer"])
@@ -348,9 +402,13 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     missing = sum(int(np.sum(r < 0)) for r in rows)
     ep0 = np.concatenate(units[:spe])
     ep1 = np.concatenate(units[spe:2 * spe])
-    if grab:
+    if ordering == "cd-grab":
+        order0 = reference.cd_first_order(n_units, workers, seed_np)
+        order1 = reference.cd_reorder(order0, prog["signs0"])
+    elif grab:
         order0 = reference.first_grab_order(n_units, seed_np)
         order1 = reference.reorder(order0, prog["signs0"])
+    if grab:
         order_mismatch = (missing + int(np.sum(ep0 != order0))
                           + int(np.sum(ep1 != order1)))
     else:
@@ -359,7 +417,15 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                                        for e in (ep0, ep1))
     steps = _steps(ds, order0, n_ref, n_micro, micro)
     t_ref = time.perf_counter()
-    ref = reference.train_steps(lambda: init(key), steps, cfg, hp, grab=grab)
+    if ordering == "cd-grab":
+        prog["signs"] = prog["signs0"][:n_ref * n_micro // workers]
+        ref = reference.train_steps_cd(
+            lambda: init(key), steps, cfg, hp, workers=workers,
+            sketch_dim=traffic["grab"]["sketch_dim"], devices=devices,
+            prog_signs=prog["signs"])
+    else:
+        ref = reference.train_steps(lambda: init(key), steps, cfg, hp,
+                                    grab=grab)
     numbers, worst = compare(prog, ref, grab)
     numbers["order_mismatch"] = float(order_mismatch)
     names = [jax.tree_util.keystr(k) for k, _ in
@@ -387,8 +453,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     kind = "end_to_end"
     if trace:
         kind = "per_layer"
-        record["trace"] = trace_reduce.reduce_trace(trace_dir, WINDOW_SPAN,
-                                                    SPANS)
+        record["trace"] = read_trace(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         device["busy_s"] = record["trace"]["busy_s"]
         device["window_s"] = record["trace"]["window_s"]
@@ -408,6 +473,13 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     print(f"window: {win['epochs']} epochs, {window_s:.3f} s, "
           f"{win['compiles']} compiles inside; set-up {setup_s:.3f} s; "
           f"reference {ref_s:.3f} s", file=log)
+    if trace:
+        tr = record["trace"]
+        print("trace: " + json.dumps({
+            k: tr[k] for k in ("window_s", "busy_s", "idle_share",
+                               "exposed_collective_share", "scope_s",
+                               "unscoped_s")} | {"steps": record["steps"]}),
+              file=log)
     for k, v in numbers.items():
         if k not in checks:
             print(f"reading {k} {v!r} (not compared)", file=log)

@@ -1,5 +1,6 @@
 """Plain reference of one training job: the model's loss and gradients, the
-GraB balance in full-pytree mode, the Algorithm-3 reorder and AdamW.
+GraB balance in full-pytree mode, CD-GraB's coordinated pair balance over W
+workers (a section of its own, below), the Algorithm-3 reorder and AdamW.
 
 Written from the published descriptions in ``jax.numpy``: float32 with every
 matrix product at ``Precision.HIGHEST``, attention as an exact softmax over
@@ -368,3 +369,263 @@ def reorder(order: np.ndarray, signs: np.ndarray) -> np.ndarray:
     a - sign in reverse order."""
     order, signs = np.asarray(order), np.asarray(signs).reshape(-1)
     return np.concatenate([order[signs > 0], order[signs < 0][::-1]])
+
+
+# ---------------------------------------------------------------------------
+# CD-GraB: W workers, pair balance on a coordinate sketch, the int8 wire
+# ---------------------------------------------------------------------------
+#
+# The semantics followed here (CD-GraB, Cooper et al. 2023, as the program
+# documents its variant): W workers each own a contiguous shard of the n
+# ordering units. The stream is time-major: at timestep t worker w takes
+# slot t of its own permutation, global position t * W + w, and a step of
+# n_micro units is T = n_micro / W timesteps. Per worker, even timesteps
+# stash the gradient and odd ones form the pair difference z_w = g_prev -
+# g. Only a fixed coordinate sketch of z takes part: k coordinates of the
+# flattened parameters, allocated to leaves in proportion to their size
+# (floor, then the remainder one by one to the leaves with the most
+# headroom, ties to the earlier leaf) and drawn per leaf, sorted, without
+# replacement, from ``numpy.random.default_rng(0)``. Each row z_w goes over
+# the wire as int8 with a per-row scale max|z_w| / 127 (1 for a zero row):
+# round half to even, clip to +-127, dequantize. The W rows of a timestep
+# are balanced in worker order against one shared running sum s: the sign
+# is +1 where <s, z> <= 0, else -1, and s += sign * z. At the epoch's end
+# the pair signs (+e, -e) order the global time-major stream by Algorithm
+# 3, and each worker's next permutation is that order restricted to its
+# shard.
+#
+# The sketch is a subsample of coordinates, so it is linear: the sketch of
+# a difference is the difference of the sketches. The reference therefore
+# keeps each worker's stash as its [k] sketch, not as a full float32 tree.
+# That keeps W gradients out of memory and lets the reference run at the
+# timed sizes on one chip or, one worker row to a chip, on W.
+
+
+def sketch_indices(shapes, k: int, seed: int = 0) -> list:
+    """Flat indices of the coordinate sketch in each leaf of ``shapes`` (in
+    ``jax.tree.leaves`` order), or None for a leaf that holds none."""
+    rng = np.random.default_rng(seed)
+    sizes = np.array([int(np.prod(s)) for s in shapes], np.int64)
+    total = int(sizes.sum())
+    target = min(int(k), total)
+    alloc = np.minimum((sizes * k) // max(total, 1), sizes)
+    while int(alloc.sum()) < target:
+        headroom = sizes - alloc
+        cand = np.flatnonzero(headroom > 0)
+        take = cand[np.argsort(-headroom[cand], kind="stable")]
+        alloc[take[:target - int(alloc.sum())]] += 1
+    out = []
+    for shape, size, a in zip(shapes, sizes, alloc):
+        if not a:
+            out.append(None)
+        elif len(shape) == 0:
+            out.append(np.zeros(1, np.int64))
+        else:
+            out.append(np.sort(rng.choice(int(size), size=int(a),
+                                          replace=False)))
+    return out
+
+
+def _sketch(tree, idx):
+    return jnp.concatenate([x.reshape(-1)[i] for x, i in
+                            zip(jax.tree.leaves(tree), idx) if i is not None])
+
+
+def int8_rows(z: np.ndarray) -> np.ndarray:
+    """The rows of ``z`` [..., k] as the int8 wire carries them, in
+    float32: per-row scale max|z| / 127 (1 for a zero row), values rounded
+    half to even and clipped to +-127, then dequantized."""
+    z = np.asarray(z, np.float32)
+    amax = np.max(np.abs(z), axis=-1, keepdims=True)
+    scale = np.where(amax > 0, amax / np.float32(127), np.float32(1))
+    scale = scale.astype(np.float32)
+    q = np.clip(np.round(z / scale), -127, 127).astype(np.float32)
+    return q * scale
+
+
+def sign_scan(s: np.ndarray, rows: np.ndarray, forced=None,
+              flip: bool = False):
+    """Balance ``rows`` [W, k] in order against the running sum ``s``
+    (float64): the sign is +1 where <s, z> <= 0, else -1. Returns the new
+    sum and the signs. With ``forced`` the sum takes those signs instead of
+    its own, so that a sign that another computation took otherwise is
+    counted once and does not move the rest. ``flip`` inverts the rule (a
+    planted fault)."""
+    s = np.array(s, np.float64)
+    signs = np.empty(len(rows), np.int64)
+    for w, z in enumerate(np.asarray(rows, np.float64)):
+        signs[w] = 1 if (float(np.dot(s, z)) <= 0) != flip else -1
+        s += (signs[w] if forced is None else float(forced[w])) * z
+    return s, signs
+
+
+def cd_scan(rows: np.ndarray, forced=None, flip: bool = False,
+            live: int | None = None):
+    """The sign scans of a run: ``rows`` [T, W, k], of which the odd
+    timesteps balance (the even ones stash and are skipped), against one
+    running sum from zero. ``forced`` [T, W] and ``flip`` as in
+    :func:`sign_scan`; workers from ``live`` on read sign 0. Returns the
+    sum and the signs [T, W]."""
+    s = np.zeros(rows.shape[-1])
+    signs = np.zeros(rows.shape[:2], np.int64)
+    for tau in range(1, len(rows), 2):
+        s, signs[tau] = sign_scan(s, rows[tau], None if forced is None
+                                  else forced[tau], flip)
+    if live is not None:
+        signs[:, live:] = 0
+    return s, signs
+
+
+def worker_mesh(devices, workers: int):
+    """A one-axis mesh ``w`` over as many of ``devices`` as divide the
+    workers: one worker row to a device where there are W of them."""
+    from jax.sharding import Mesh
+
+    n = max(d for d in range(1, min(len(devices), workers) + 1)
+            if workers % d == 0)
+    return Mesh(np.array(devices[:n]), ("w",))
+
+
+def _timestep(z, prec, idx, mesh):
+    """One timestep of the W workers: each worker's loss and float32
+    gradient on its microbatch, the sketch of that gradient, and its
+    gradient added, times the worker's weight (1, or 0 where a planted
+    fault leaves it out), into its device's partial sum. Workers are split
+    over the mesh's devices, and a device takes its own one at a time."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(params, acc, tokens, labels, weight):
+        p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+
+        def row(a, xs):
+            tok, lab, wt = xs
+
+            def mean_loss(p):
+                return jax.vmap(lambda t, l: loss(p, t, l, z, prec))(
+                    tok, lab).mean()
+
+            val, g = jax.value_and_grad(mean_loss)(p32)
+            a = jax.tree.map(lambda u, v: u + wt * v, a, g)
+            return a, (val, _sketch(g, idx))
+
+        a, (vals, sks) = jax.lax.scan(
+            row, jax.tree.map(lambda x: x[0], acc), (tokens, labels, weight))
+        return jax.tree.map(lambda x: x[None], a), vals, sks
+
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(), P("w"), P("w"), P("w"), P("w")),
+                      out_specs=(P("w"), P("w"), P("w")), check_vma=False)
+    return jax.jit(f, donate_argnums=(1,))
+
+
+def train_steps_cd(init, steps, cfg: dict, hp: dict, *, workers: int,
+                   sketch_dim: int, devices, prog_signs=None,
+                   prec: str = "f32", keep: float = 1.0,
+                   drop_row=None, flip: bool = False) -> dict:
+    """The first optimizer steps of CD-GraB with W = ``workers``, from the
+    parameters ``init()`` makes.
+
+    ``steps``: list of (tokens, labels), each ``[n_micro, micro, T]`` in
+    the time-major order (unit j of a step is worker j % W's at timestep
+    j // W). A step's gradient is the mean over its microbatches, whichever
+    worker took each. ``prog_signs`` ([timesteps, W], the program's sign
+    buffer): the running sum takes these signs, and the reference's own are
+    returned beside them. ``keep`` < 1 leaves out the last workers' share
+    of every timestep (their gradients, rows and signs; the mean is over
+    the rest), ``drop_row`` = w zeroes worker w's row before the scan and
+    ``flip`` inverts the sign rule: planted faults. Returns what
+    :func:`train_steps` returns (``sum``: the norm of the running sum),
+    ``signs`` [timesteps, W] and the rows the scans read (``rows``
+    [timesteps, W, k], zero on stash timesteps), which the gradients alone
+    fix: :func:`cd_scan` of them with other forced signs gives the sum and
+    signs that a run forced to those would."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    z = sizes(cfg)
+    mesh = worker_mesh(devices, workers)
+    repl = NamedSharding(mesh, P())
+    rows_sh = NamedSharding(mesh, P("w"))
+    shapes = [x.shape for x in jax.tree.leaves(jax.eval_shape(init))]
+    idx = [None if i is None else jnp.asarray(i)
+           for i in sketch_indices(shapes, sketch_dim)]
+    step_t = _timestep(z, prec, idx, mesh)
+    adamw = _adamw(hp)
+    zeros = jax.jit(lambda: jax.tree.map(
+        lambda x: jnp.zeros(x.shape, jnp.float32), jax.eval_shape(init)),
+        out_shardings=repl)
+    partial = jax.jit(lambda: jax.tree.map(
+        lambda x: jnp.zeros((mesh.size,) + x.shape, jnp.float32),
+        jax.eval_shape(init)), out_shardings=rows_sh)
+    n_keep = max(1, int(round(workers * keep)))
+    weight = np.asarray([1.0] * n_keep + [0.0] * (workers - n_keep),
+                        np.float32)
+    n = len(steps[0][0]) // workers * n_keep
+    mean = jax.jit(lambda a: jax.tree.map(lambda x: x.sum(0) / n, a),
+                   out_shardings=repl)
+
+    p = jax.device_put(init(), repl)
+    m, v = zeros(), zeros()
+    losses, scanned, out = [], [], {}
+    for i, (tokens, labels) in enumerate(steps, start=1):
+        tw = tokens.reshape((-1, workers) + tokens.shape[1:])
+        lw = labels.reshape((-1, workers) + labels.shape[1:])
+        acc = partial()
+        vals = []
+        for t in range(tw.shape[0]):
+            acc, val, sk = step_t(p, acc, jax.device_put(tw[t], rows_sh),
+                                  jax.device_put(lw[t], rows_sh),
+                                  jax.device_put(weight, rows_sh))
+            vals.extend(np.asarray(jax.device_get(val))[:n_keep])
+            sk = np.asarray(jax.device_get(sk), np.float32)
+            rows = np.zeros_like(sk)
+            if len(scanned) % 2 == 0:
+                stash = sk
+            else:
+                rows = int8_rows(stash - sk)
+                rows[n_keep:] = 0.0
+                if drop_row is not None:
+                    rows[drop_row] = 0.0
+            scanned.append(rows)
+        grads = mean(acc)
+        del acc
+        if i == 1:
+            out["grad_raw"] = leaf_norms(grads)
+        p, m, v, clipped = adamw(p, m, v, grads, jnp.float32(i),
+                                 jnp.float32(hp["lr"]))
+        if i == 1:
+            out["grad"] = leaf_norms(clipped)
+        del clipped
+        losses.append(float(np.mean(vals)))
+    del m, v
+    out["losses"] = np.asarray(losses)
+    out["rows"] = np.stack(scanned)
+    s, out["signs"] = cd_scan(out["rows"], prog_signs, flip, n_keep)
+    out["sum"] = np.asarray([np.linalg.norm(s)])
+    p0 = jax.device_put(init(), repl)
+    out["update"] = leaf_norms(jax.tree.map(
+        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, p0))
+    return out
+
+
+def cd_first_order(n: int, workers: int, seed: int) -> np.ndarray:
+    """CD-GraB's first epoch: a random permutation from the seed, restricted
+    to each worker's contiguous shard, interleaved time-major."""
+    init = np.random.default_rng((seed, 0)).permutation(n)
+    m = n // workers
+    return np.stack([init[init // m == w] for w in range(workers)]
+                    ).T.reshape(-1)
+
+
+def cd_reorder(order: np.ndarray, signs: np.ndarray) -> np.ndarray:
+    """The next epoch's time-major order: each worker's pair signs
+    (``signs`` [T, W], zero on stash rows) expanded to (+e, -e), Algorithm
+    3 on the global stream, and the result restricted to each shard."""
+    signs = np.asarray(signs)
+    workers = signs.shape[1]
+    full = np.empty_like(signs)
+    full[0::2] = signs[1::2]
+    full[1::2] = -signs[1::2]
+    out = reorder(order, full.reshape(-1))
+    m = len(order) // workers
+    return np.stack([out[out // m == w] for w in range(workers)]
+                    ).T.reshape(-1)

@@ -1,6 +1,12 @@
 import os
 import sys
 
+# four host devices for the four-chip cell's mesh; set before JAX starts
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=4").strip()
+
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 for p in (os.path.join(ROOT, "src"), BENCH):

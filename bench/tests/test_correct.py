@@ -143,7 +143,7 @@ def test_broken_step_is_not_correct(root, cell, fault, monkeypatch):
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_float8_control_is_not_correct(root, cell):
-    nums = calibrate.reference_in_place(root, cell, 3, "control")
+    nums = calibrate.reference_in_place(root, cell, 3, ["control"])["control"]
     lim = json.loads(open(os.path.join(root, "bench", "limits",
                                        f"{cell}.json")).read())
     checks, correct = harness.decide(nums, lim)

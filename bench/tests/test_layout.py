@@ -20,7 +20,7 @@ def test_every_cell_resolves():
         cfg = lay.config(w["config"])
         assert cfg["name"] == w["config"]
         traffic = lay.traffic(w["traffic"])
-        assert traffic["ordering"] in ("grab", "rr")
+        assert traffic["ordering"] in ("grab", "cd-grab", "rr")
         limits = lay.limits(w["name"])
         assert limits and all(v >= 0 for v in limits.values())
         for kind in ("end_to_end", "per_layer"):

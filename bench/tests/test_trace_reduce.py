@@ -4,6 +4,7 @@ import os
 import pytest
 
 import trace_reduce as tr
+import trace_scopes as ts
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
                        "spans.xplane.pb")
@@ -11,7 +12,7 @@ MS = 1e6   # ns
 
 
 def test_recorded_cpu_trace_spans():
-    ev = tr.read_events(FIXTURE, ["bench_window", "loader_wait", "dispatch",
+    ev = ts.read_events(FIXTURE, ["bench_window", "loader_wait", "dispatch",
                                   "epoch_reorder"])
     assert ev["devices"] == {}             # a CPU trace has no device plane
     out = tr.reduce_events(ev, "bench_window")
@@ -44,6 +45,46 @@ def test_busy_union_and_idle_share():
 def test_exposed_collective_share():
     out = tr.reduce_events(_events(), "window")
     assert out["exposed_collective_share"] == pytest.approx(0.20)
+
+
+def test_a_collective_inside_a_loop_is_exposed():
+    """The loop's own event spans its body; only the body's other ops hide
+    the collective."""
+    ev = _events()
+    ev["devices"]["/device:TPU:0"].append((0, 100 * MS, "while.3"))
+    out = tr.reduce_events(ev, "window")
+    assert out["exposed_collective_share"] == pytest.approx(0.20)
+    assert out["busy_s"] == pytest.approx(0.100)
+
+
+def test_an_op_that_reads_a_collective_is_no_collective():
+    """The trace names each op by its HLO text, operands included: the
+    fusion that reads the gathered weight is compute."""
+    ev = _events()
+    ops = ev["devices"]["/device:TPU:0"]
+    ops[1] = (20 * MS, 50 * MS, "%all-gather-done.2 = bf16[8]{0} "
+              "all-gather-done(bf16[8]{0} %all-gather-start.2)")
+    ops[2] = (60 * MS, 70 * MS, "%fusion.1 = bf16[8]{0} "
+              "fusion(bf16[8]{0} %all-gather-done.2), kind=kOutput")
+    out = tr.reduce_events(ev, "window")
+    assert out["exposed_collective_share"] == pytest.approx(0.20)
+
+
+def test_a_fused_collective_is_exposed():
+    """A fusion that the program's HLO marks a collective (the fifth field
+    of an event read with the trace's HLO) counts as one, whatever its
+    name; one the HLO marks compute hides a collective under it."""
+    ev = _events()
+    ops = ev["devices"]["/device:TPU:0"]
+    coll = [e for e in ops if tr.COLLECTIVE.match(tr.op_name(e[2]))]
+    ops[:] = [e + (None, None) if e not in coll else
+              (e[0], e[1], "%fusion.658 = bf16[8]{0} fusion(bf16[32]{0} "
+               "%p), kind=kOutput", None, True) for e in ops]
+    out = tr.reduce_events(ev, "window")
+    assert out["exposed_collective_share"] == pytest.approx(0.20)
+    ops[:] = [e[:4] + (False,) for e in ops]
+    out = tr.reduce_events(ev, "window")
+    assert out["exposed_collective_share"] == 0.0
 
 
 def test_breakdown_ops_and_gap_attribution():

@@ -37,10 +37,10 @@ def test_op_names_from_the_hlo_a_trace_carries():
     ran; each instruction's ``op_name`` comes back under the program's
     name as the trace names it."""
     with open(FIXTURE, "rb") as f:
-        names = ts.hlo_op_names(f.read())
+        names = ts.hlo_ops(f.read())
     prog = [p for p in names if p.startswith("jit__lambda(")]
     assert len(prog) == 1
-    assert names[prog[0]]["tanh.0"] == "jit(<lambda>)/tanh"
+    assert names[prog[0]]["tanh.0"] == ("jit(<lambda>)/tanh", False)
 
 
 def test_ops_take_the_op_name_of_the_program_they_run_in():
@@ -50,15 +50,79 @@ def test_ops_take_the_op_name_of_the_program_they_run_in():
     programs = [(0, 100, "jit_train_step(1)"),
                 (200, 250, "jit_grab_rollover(2)")]
     op_names = {"jit_train_step(1)": {
-                    "fusion.1": "jit(train_step)/while/body/grab_balance/add",
-                    "copy.2": None},
-                "jit_grab_rollover(2)": {"copy.2": None}}
+                    "fusion.1": ts.HloOp(
+                        "jit(train_step)/while/body/grab_balance/add", False),
+                    "copy.2": ts.HloOp(None, False)},
+                "jit_grab_rollover(2)": {"copy.2": ts.HloOp(None, False)}}
     ops = [(10, 20, "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)"),
            (30, 40, "%copy.2 = f32[8]{0} copy(f32[8]{0} %p)"),
            (210, 220, "%copy.2 = f32[8]{0} copy(f32[8]{0} %p)"),
            (150, 160, "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)")]
-    got = [e[3] for e in ts.scope_events(ops, programs, op_names)]
-    assert got == ["grab_balance", None, "grab_rollover", None]
+    got = [e[3:] for e in ts.scope_events(ops, programs, op_names)]
+    assert got == [("grab_balance", False), (None, False),
+                   ("grab_rollover", False), (None, None)]
+
+
+def _varint(x: int) -> bytes:
+    out = b""
+    while True:
+        low, x = x & 0x7F, x >> 7
+        out += bytes([low | 0x80 if x else low])
+        if not x:
+            return out
+
+
+def _msg(n: int, payload) -> bytes:
+    """Field ``n`` of a protobuf message: an int, or bytes/str."""
+    if isinstance(payload, int):
+        return _varint(n << 3) + _varint(payload)
+    payload = payload.encode() if isinstance(payload, str) else payload
+    return _varint(n << 3 | 2) + _varint(len(payload)) + payload
+
+
+def _instruction(name, opcode, called=()):
+    return _msg(2, _msg(1, name) + _msg(2, opcode)
+                + (_msg(38, b"".join(_varint(c) for c in called))
+                   if called else b""))
+
+
+def _xspace(program: str, computations) -> bytes:
+    """A serialized XSpace whose metadata plane carries one program's HLO:
+    ``computations`` is ``[(id, [instruction, ...])]``."""
+    module = b"".join(_msg(3, _msg(1, f"c{cid}") + b"".join(ins)
+                           + _msg(5, cid)) for cid, ins in computations)
+    stat = _msg(1, 7) + _msg(6, _msg(1, module))
+    event_md = _msg(1, 3) + _msg(2, program) + _msg(5, stat)
+    stat_md = _msg(1, 7) + _msg(2, "Hlo Proto")
+    plane = (_msg(2, ts.METADATA_PLANE)
+             + _msg(4, _msg(1, 3) + _msg(2, event_md))
+             + _msg(5, _msg(1, 7) + _msg(2, stat_md)))
+    return _msg(1, plane)
+
+
+def test_a_fusion_around_a_collective_is_a_collective():
+    """XLA fuses a reduce-scatter into a fusion of another name (the
+    four-chip cell's ``fusion.658`` calls ``all-reduce-scatter.7``, which
+    holds ``all-reduce.150``): the fusion is a collective by the HLO it
+    calls, at any depth; a loop whose body holds one is not, nor is a
+    fusion of compute."""
+    raw = _xspace("jit_train_step(1)", [
+        (1, [_instruction("fusion.658", "fusion", [2]),
+             _instruction("fusion.1", "fusion", [3]),
+             _instruction("while.3", "while", [4, 3]),
+             _instruction("async-collective-start", "fusion", [5]),
+             _instruction("all-gather-start.2", "all-gather-start")]),
+        (2, [_instruction("all-reduce.150", "all-reduce", [3])]),
+        (3, [_instruction("add.1", "add")]),
+        (4, [_instruction("all-gather.9", "all-gather")]),
+        (5, [_instruction("fusion.9", "fusion", [2])]),
+    ])
+    ops = ts.hlo_ops(raw)["jit_train_step(1)"]
+    got = {name: op.collective for name, op in ops.items()}
+    assert got == {"fusion.658": True, "fusion.1": False, "while.3": False,
+                   "async-collective-start": True,
+                   "all-gather-start.2": True, "all-reduce.150": True,
+                   "add.1": False, "all-gather.9": True, "fusion.9": True}
 
 
 def _events():
@@ -147,3 +211,56 @@ def test_cli_prints_one_json_object(capsys):
 
     assert ts.main([FIXTURE, "--window", "bench_window"]) == 0
     assert json.loads(capsys.readouterr().out)["steps"] == 3
+
+
+def test_harness_reads_the_trace_once_and_as_before(tmp_path):
+    """The harness's reading of a trace, which adds the loop's nested spans
+    and the scope times, gives the numbers that the readers of
+    ``loop.epoch_boundary_ms``, ``device.idle_share``, ``step.mfu`` and
+    ``data.loader_wait_ms`` take as the reduction with the old span list
+    gave them."""
+    import shutil
+
+    import harness
+    import trace_reduce as tr
+    from layout import Layout
+
+    shutil.copy(FIXTURE, tmp_path / "t.xplane.pb")
+    old = tr.reduce_events(ts.read_events(FIXTURE, (
+        "loader_wait", "dispatch", "epoch_reorder", "ckpt_save",
+        harness.WINDOW_SPAN)), harness.WINDOW_SPAN)
+    new = harness.read_trace(str(tmp_path))
+    assert new["scope_s"] == {} and new["unscoped_s"] == 0.0
+    for k in old:
+        if k in ("device_ops", "idle_gaps"):
+            continue             # the breakdown: ops and gaps by scope
+        if k in ("spans", "span_idle"):
+            assert {n: v for n, v in new[k].items() if n in old[k]} == old[k]
+        else:
+            assert new[k] == old[k], k
+    lay = Layout(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    for name in ("loop.epoch_boundary_ms", "device.idle_share", "step.mfu",
+                 "data.loader_wait_ms"):
+        read = lay.reader(name)
+        run = {"trace": None, "tokens": 1e5, "chips": 1,
+               "flops_per_token": 1e9, "peak_flops": 1e12}
+        assert read(dict(run, trace=new)) == read(dict(run, trace=old))
+
+
+def test_harness_trace_reading_on_device_events():
+    """On events with a device plane and nested boundary spans, the
+    boundary's idle and the window's idle share do not move with the spans
+    added; the scope times come beside them."""
+    import harness
+    import trace_reduce as tr
+
+    ev = _events()
+    old = tr.reduce_events(dict(ev, spans=[
+        s for s in ev["spans"] if s[2] not in ("sign_fetch", "rollover")]),
+        "window")
+    new = tr.reduce_events(ev, "window")
+    assert new["span_idle"]["epoch_reorder"] == \
+        old["span_idle"]["epoch_reorder"]
+    assert new["idle_share"] == old["idle_share"]
+    assert {"sign_fetch", "rollover"} <= set(harness.SPANS)

@@ -1,12 +1,12 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to the benchmark's numbers.
 
-Reads the trace with ``jax.profiler.ProfileData`` and nothing else. Device
-operations are the events of the ``XLA Ops`` line of each chip's plane
-(``/device:TPU:<n>``; other ``/device:`` planes hold no chip); host spans
-are the ``jax.profiler.TraceAnnotation`` events of the ``/host:CPU`` plane.
-All times are in the trace's own clock, in nanoseconds, and every quantity is
-taken inside one window: the span named ``window`` (the benchmark opens it
-around its measured window).
+``trace_scopes.read_events`` reads the trace with
+``jax.profiler.ProfileData``. Device operations are the events of the ``XLA
+Ops`` line of each chip's plane (``/device:TPU:<n>``; other ``/device:``
+planes hold no chip); host spans are the ``jax.profiler.TraceAnnotation``
+events of the ``/host:CPU`` plane. All times are in the trace's own clock,
+in nanoseconds, and every quantity is taken inside one window: the span
+named ``window`` (the benchmark opens it around its measured window).
 """
 from __future__ import annotations
 
@@ -29,29 +29,6 @@ def find_trace(log_dir: str) -> str:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     return paths[-1]
-
-
-def read_events(path: str, span_names) -> dict:
-    """``{"devices": {plane: [(start, end, name)]}, "spans": [(start, end,
-    name)]}`` with the host spans restricted to ``span_names``."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    devices, spans = {}, []
-    wanted = set(span_names)
-    for plane in data.planes:
-        if DEVICE_PLANE.match(plane.name):
-            evs = []
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    evs.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
-                               for e in line.events)
-            devices[plane.name] = sorted(evs)
-        elif plane.name == HOST_PLANE:
-            for line in plane.lines:
-                spans.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
-                             for e in line.events if e.name in wanted)
-    return {"devices": devices, "spans": sorted(spans)}
 
 
 def merge(intervals, lo: float, hi: float):
@@ -105,9 +82,22 @@ def subtract(merged, other):
 
 def exposed_collective_ns(events, lo: float, hi: float) -> float:
     """Time in ``[lo, hi]`` in which a collective runs on a device and no
-    other operation does."""
-    coll = merge([e for e in events if COLLECTIVE.search(e[2])], lo, hi)
-    rest = merge([e for e in events if not COLLECTIVE.search(e[2])], lo, hi)
+    other operation does. An event ``(start, end, name, scope,
+    collective)`` whose fifth field is set (``trace_scopes.hlo_ops``, from
+    the program's HLO) is a collective by it, a fusion that XLA built
+    around a collective among them; any other by its own name (an event's
+    text also names its operands, and an op that reads a gathered weight
+    is no collective). A control-flow op (``CONTAINERS``) is an event that
+    spans the ops of its body, a collective among them, so it counts as no
+    other operation."""
+    def is_coll(e):
+        if len(e) > 4 and e[4] is not None:
+            return e[4]
+        return COLLECTIVE.match(op_name(e[2])) is not None
+
+    coll = merge([e for e in events if is_coll(e)], lo, hi)
+    rest = merge([e for e in events if not is_coll(e)
+                  and not op_name(e[2]).startswith(CONTAINERS)], lo, hi)
     return length(subtract(coll, rest))
 
 
@@ -152,8 +142,10 @@ def op_name(name: str) -> str:
 def reduce_events(ev: dict, window: str, top: int = 10) -> dict:
     """Busy time, idle share, span durations and the device's idle time
     inside each span, exposed collectives and the breakdown, all inside the
-    ``window`` span. Control-flow ops (a loop and the ops in its body are
-    both events) count towards busy time but not in ``device_ops``."""
+    ``window`` span. ``ev``: ``{"devices": {plane: [(start, end, name,
+    ...)]}, "spans": [(start, end, name)]}``. Control-flow ops (a loop and
+    the ops in its body are both events) count towards busy time but not
+    in ``device_ops``."""
     lo, hi = window_of(ev["spans"], window)
     win = hi - lo
     busy, exposed, gap_list, merged_all = [], [], [], []
@@ -163,7 +155,7 @@ def reduce_events(ev: dict, window: str, top: int = 10) -> dict:
         merged_all.append(merged)
         busy.append(length(merged))
         exposed.append(exposed_collective_ns(events, lo, hi))
-        for a, b, name in events:
+        for a, b, name, *_ in events:
             name = op_name(name)
             d = min(b, hi) - max(a, lo)
             if d > 0 and not name.startswith(CONTAINERS):
@@ -193,7 +185,3 @@ def reduce_events(ev: dict, window: str, top: int = 10) -> dict:
         "devices": n_dev if busy else 0,
     }
 
-
-def reduce_trace(log_dir: str, window: str, span_names) -> dict:
-    ev = read_events(find_trace(log_dir), list(span_names) + [window])
-    return reduce_events(ev, window)

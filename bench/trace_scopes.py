@@ -5,7 +5,7 @@ The program names the layers of its step with ``jax.named_scope``
 ``optimizer``; ``repro/train/loop.py``: ``grab_rollover``). The names reach
 each HLO instruction's ``op_name`` metadata. A TPU trace's op events carry
 only the instruction's name, and the trace carries each program's HLO
-(``hlo_op_names``): an op's program is the ``XLA Modules`` event it runs
+(``hlo_ops``): an op's program is the ``XLA Modules`` event it runs
 in, and its ``op_name`` that program's instruction's. An op belongs to the
 first of the five names in its ``op_name``, as a path component or inside a
 wrapper such as ``transpose(jvp(fwd_bwd))`` or ``jit(fwd_bwd)``; else to
@@ -38,6 +38,7 @@ import os
 import re
 import sys
 from collections import defaultdict
+from typing import NamedTuple
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,6 +52,9 @@ MODULES_LINE = "XLA Modules"
 METADATA_PLANE = "/host:metadata"
 _WORD = re.compile(r"[A-Za-z0-9_]+")
 _PROGRAM = re.compile(r"^jit_(\w+)\(")
+COLLECTIVE_OPCODES = ("all-gather", "all-reduce", "reduce-scatter",
+                      "all-to-all", "ragged-all-to-all", "collective-permute",
+                      "collective-broadcast", "send", "recv")
 
 
 def scope_of(op_name) -> str | None:
@@ -105,12 +109,31 @@ def _sub(msg, number: int):
     return [v for n, v in _fields(msg) if n == number]
 
 
-def hlo_op_names(raw: bytes) -> dict:
-    """``{program: {instruction: op_name}}`` from the HLO of each program
+class HloOp(NamedTuple):
+    op_name: str | None
+    collective: bool
+
+
+def _ints(v) -> list:
+    """A repeated integer field's values, packed or not."""
+    if isinstance(v, int):
+        return [v]
+    out, i = [], 0
+    while i < len(v):
+        x, i = _varint(v, i)
+        out.append(x)
+    return out
+
+
+def hlo_ops(raw: bytes) -> dict:
+    """``{program: {instruction: HloOp}}`` from the HLO of each program
     that a trace (serialized ``XSpace``) carries: the ``Hlo Proto`` stats of
     its ``/host:metadata`` plane, keyed by the program's name as the
     device's ``XLA Modules`` line names it. The device op events carry
-    only the instruction's name; its ``op_name`` is here."""
+    only the instruction's name; its ``op_name`` is here, and whether it is
+    a collective: its opcode is one (``COLLECTIVE_OPCODES``), or it is no
+    control-flow op and a computation it calls holds one at any depth, as
+    a fusion that XLA built around a reduce-scatter does."""
     out = {}
     for plane in _sub(raw, 1):                      # XSpace.planes
         if bytes(next(iter(_sub(plane, 2)), b"")) != METADATA_PLANE.encode():
@@ -127,44 +150,79 @@ def hlo_op_names(raw: bytes) -> dict:
                     f = dict(_fields(stat))
                     if stat_names.get(f.get(1)) != "Hlo Proto" or 6 not in f:
                         continue
-                    names = out.setdefault(program, {})
+                    ops = out.setdefault(program, {})
                     for module in _sub(f[6], 1):    # HloProto.hlo_module
-                        for comp in _sub(module, 3):
-                            for ins in _sub(comp, 2):
-                                f_ins = dict(_fields(ins))
-                                op = dict(_fields(f_ins.get(7, b"")))
-                                names[bytes(f_ins[1]).decode()] = (
-                                    bytes(op[2]).decode() if 2 in op
-                                    else None)
+                        ops.update(_module_ops(module))
     return out
 
 
-def scope_events(ops, programs, op_names):
-    """``(start, end, name, scope)`` of each device op ``(start, end,
-    name)``: the op's program is the ``XLA Modules`` event ``(start, end,
-    program)`` it runs in, its ``op_name`` that program's, and its scope the
-    first of ``SCOPES`` there, else the program's own (``program_scope``)."""
+def _module_ops(module) -> dict:
+    """``{instruction: HloOp}`` of one ``HloModuleProto``."""
+    comps, ins_all = {}, []
+    for comp in _sub(module, 3):                    # computations
+        ins_of = []
+        for ins in _sub(comp, 2):                   # instructions
+            name = opcode = meta = None
+            called = []
+            for n, v in _fields(ins):
+                if n == 1:
+                    name = bytes(v).decode()
+                elif n == 2:
+                    opcode = bytes(v).decode()
+                elif n == 7:
+                    meta = dict(_fields(v))
+                elif n == 38:                       # called_computation_ids
+                    called.extend(_ints(v))
+            op_name = bytes(meta[2]).decode() if meta and 2 in meta else None
+            ins_of.append((opcode or "", called))
+            ins_all.append((name, opcode or "", called, op_name))
+        comps[dict(_fields(comp)).get(5)] = ins_of     # id
+    holds = {}
+
+    def holds_collective(cid) -> bool:
+        if cid not in holds:
+            holds[cid] = False                      # a cycle holds none
+            holds[cid] = any(
+                op.startswith(COLLECTIVE_OPCODES)
+                or any(holds_collective(c) for c in called)
+                for op, called in comps.get(cid, ()))
+        return holds[cid]
+
+    return {name: HloOp(op_name, opcode.startswith(COLLECTIVE_OPCODES) or (
+                not opcode.startswith(tr.CONTAINERS)
+                and any(holds_collective(c) for c in called)))
+            for name, opcode, called, op_name in ins_all}
+
+
+def scope_events(ops, programs, hlo):
+    """``(start, end, name, scope, collective)`` of each device op
+    ``(start, end, name)``: the op's program is the ``XLA Modules`` event
+    ``(start, end, program)`` it runs in, its ``op_name`` and whether it is
+    a collective that program's (``hlo_ops``; None where the trace carries
+    no HLO of it), and its scope the first of ``SCOPES`` in the
+    ``op_name``, else the program's own (``program_scope``)."""
     programs = sorted(programs)
     starts = [p[0] for p in programs]
     out = []
     for a, b, name in ops:
         i = bisect.bisect_right(starts, a) - 1
         program = programs[i][2] if i >= 0 and a < programs[i][1] else None
-        op_name = op_names.get(program, {}).get(tr.op_name(name))
+        op = hlo.get(program, {}).get(tr.op_name(name))
         out.append((a, b, name,
-                    scope_of(op_name) or program_scope(program)))
+                    scope_of(op and op.op_name) or program_scope(program),
+                    op and op.collective))
     return out
 
 
 def read_events(path: str, span_names) -> dict:
-    """``trace_reduce.read_events``, with each device op's scope as a fourth
-    field: ``{"devices": {plane: [(start, end, name, scope)]}, "spans":
-    [(start, end, name)]}``."""
+    """``{"devices": {plane: [(start, end, name, scope)]}, "spans": [(start,
+    end, name)]}``: each chip's device ops with the scope of each, and the
+    host spans restricted to ``span_names``."""
     from jax.profiler import ProfileData
 
     with open(path, "rb") as f:
         raw = f.read()
-    op_names = hlo_op_names(raw)
+    hlo = hlo_ops(raw)
     data = ProfileData.from_serialized_xspace(raw)
     devices, spans = {}, []
     wanted = set(span_names)
@@ -176,8 +234,7 @@ def read_events(path: str, span_names) -> dict:
                     evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
                            for e in line.events]
                     (ops if line.name == tr.OPS_LINE else programs).extend(evs)
-            devices[plane.name] = sorted(scope_events(ops, programs,
-                                                      op_names))
+            devices[plane.name] = sorted(scope_events(ops, programs, hlo))
         elif plane.name == tr.HOST_PLANE:
             for line in plane.lines:
                 spans.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
@@ -220,7 +277,7 @@ def reduce_events(ev: dict, window, top: int = 10) -> dict:
         merged = tr.merge(events, lo, hi)
         busy += tr.length(merged)
         gap_list.extend(tr.gaps(merged, lo, hi))
-        for a, b, name, scope in events:
+        for a, b, name, scope, *_ in events:
             name = tr.op_name(name)
             d = min(b, hi) - max(a, lo)
             if d <= 0 or name.startswith(tr.CONTAINERS):
