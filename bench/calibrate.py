@@ -9,6 +9,8 @@
 comparison) for each seed, in one process: the lower readings.
 ``control``: the reference put in the program's place, computed in float8
 (``reference.py``, ``precision="fp8"``), against the float32 reference.
+Every stand-in and the reference that judges it run the configuration's
+model module (``Layout.model``).
 ``half``: the reference in the program's place with half of each step's
 microbatches left out and the mean taken over the rest (a planted fault;
 in a CD-GraB cell the last half of the workers of every timestep).
@@ -62,6 +64,7 @@ def reference_in_place(root: str, workload: str, seed: int, modes,
     lay = Layout(root)
     cell = lay.cell(workload)
     cfg = lay.config(cell["config"])
+    model = lay.model(cfg)
     traffic = lay.traffic(cell["traffic"])
     grab = traffic["ordering"] != "rr"
     micro, n_micro = traffic["micro"], traffic["n_micro"]
@@ -70,7 +73,7 @@ def reference_in_place(root: str, workload: str, seed: int, modes,
     ds = bench_data.TokenRows(n_units * micro, traffic["seq_len"],
                               cfg["vocab_size"], seed_np)
     key = reference.make_key(seed)
-    init = jax.jit(lambda k: reference.init_params(k, cfg))
+    init = jax.jit(lambda k: model.init_params(k, cfg))
     hp = traffic["optimizer"]
     out = {}
     if traffic["ordering"] == "cd-grab":
@@ -81,7 +84,7 @@ def reference_in_place(root: str, workload: str, seed: int, modes,
         run = lambda **a: reference.train_steps_cd(
             lambda: init(key), steps, cfg, hp, workers=workers,
             sketch_dim=traffic["grab"]["sketch_dim"],
-            devices=devices or jax.devices(), **a)
+            devices=devices or jax.devices(), model=model, **a)
         ref = run()
         for mode in modes:
             alt = run(**STAND_INS[mode])
@@ -94,10 +97,11 @@ def reference_in_place(root: str, workload: str, seed: int, modes,
         raise ValueError("the sign faults are CD-GraB cells' alone")
     steps = harness._steps(ds, reference.first_grab_order(n_units, seed_np),
                            traffic["reference_steps"], n_micro, micro)
-    ref = reference.train_steps(lambda: init(key), steps, cfg, hp, grab=grab)
+    ref = reference.train_steps(lambda: init(key), steps, cfg, hp, grab=grab,
+                                model=model)
     for mode in modes:
         alt = reference.train_steps(lambda: init(key), steps, cfg, hp,
-                                    grab=grab, **STAND_INS[mode])
+                                    grab=grab, model=model, **STAND_INS[mode])
         out[mode] = harness.compare(alt, ref, grab)[0]
     return out
 

@@ -8,7 +8,8 @@ from the end of epoch 0 to the first epoch boundary at or after ``seconds``;
 a hook at each boundary waits for the state and reads the host clock, so the
 loop keeps its one sync per epoch and no more.
 
-What the window ran is checked against ``reference.py``: the first
+What the window ran is checked against ``reference.py``, with the model
+module the configuration names (``Layout.model``): the first
 ``reference_steps`` steps of epoch 0 (the same compiled step and state the
 window then drives) and the order of epochs 0 and 1. The probes read the
 loop's state after the first and the last of those steps, in set-up; the
@@ -199,15 +200,16 @@ def _make_probe(on_dispatch, last: int):
 def read_trace(trace_dir: str) -> dict:
     """``trace_reduce.reduce_events`` of the trace in ``trace_dir`` inside
     the window, with the device seconds of each named scope of the step
-    (``scope_s``) and of the rest (``unscoped_s``) by
-    ``trace_scopes.reduce_events``, whose top ops, named
-    ``<scope>/<op>``, and idle gaps, labelled ``parent/child``, make the
-    breakdown. The trace is read once."""
+    (``scope_s``), of each scope nested in one (``nested_s``) and of the
+    rest (``unscoped_s``) by ``trace_scopes.reduce_events``, whose top ops,
+    named ``<scope>/<op>``, and idle gaps, labelled ``parent/child``, make
+    the breakdown. The trace is read once."""
     ev = trace_scopes.read_events(trace_reduce.find_trace(trace_dir),
                                   SPANS + (WINDOW_SPAN,))
     out = trace_reduce.reduce_events(ev, WINDOW_SPAN)
     scopes = trace_scopes.reduce_events(ev, WINDOW_SPAN)
-    for k in ("scope_s", "unscoped_s", "device_ops", "idle_gaps"):
+    for k in ("scope_s", "nested_s", "unscoped_s", "device_ops",
+              "idle_gaps"):
         out[k] = scopes[k]
     return out
 
@@ -239,6 +241,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     cfg = lay.config(cell["config"])
     traffic = lay.traffic(cell["traffic"])
     limits = lay.limits(workload)
+    ref_model = lay.model(cfg)
 
     import jax
 
@@ -264,7 +267,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     seed_np = seed % (2 ** 63)
     model = get_config(cfg["program"]["arch"])[0].with_(
         **cfg["program"]["overrides"])
-    _check_model(model, cfg)
+    _check_model(model, ref_model.program_fields(cfg))
     ordering = traffic["ordering"]
     if ordering not in ORDERINGS:
         raise ValueError(f"unknown ordering {ordering!r}")
@@ -279,7 +282,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     n_ref = traffic["reference_steps"]
 
     key = reference.make_key(seed)
-    init = jax.jit(lambda k: reference.init_params(k, cfg))
+    init = jax.jit(lambda k: ref_model.init_params(k, cfg))
     # made on the chip in one call, handed to the loop from the host: the
     # loop copies its arguments in, and a second copy of the weights left on
     # the chip would not fit beside the deepest step that does
@@ -422,10 +425,10 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         ref = reference.train_steps_cd(
             lambda: init(key), steps, cfg, hp, workers=workers,
             sketch_dim=traffic["grab"]["sketch_dim"], devices=devices,
-            prog_signs=prog["signs"])
+            prog_signs=prog["signs"], model=ref_model)
     else:
         ref = reference.train_steps(lambda: init(key), steps, cfg, hp,
-                                    grab=grab)
+                                    grab=grab, model=ref_model)
     numbers, worst = compare(prog, ref, grab)
     numbers["order_mismatch"] = float(order_mismatch)
     names = [jax.tree_util.keystr(k) for k, _ in
@@ -438,13 +441,15 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     # --- metrics -----------------------------------------------------------
     tokens = win["epochs"] * spe * n_micro * micro * seq
     peaks = _peaks(lay.bench_dir, devices[0].device_kind)
+    per_token = getattr(ref_model, "flops_per_token", flops.per_token)
     record = {
         "setup_s": setup_s, "window_s": window_s, "tokens": tokens,
         "steps": win["epochs"] * spe, "epochs": win["epochs"],
         "chips": len(devices), "peak_bytes": peak, "gauges": gauges,
         "grab_state_bytes": prog.get("grab_bytes"),
-        "flops_per_token": flops.per_token(cfg, seq),
+        "flops_per_token": per_token(cfg, seq),
         "peak_flops": peaks["bf16_flops_per_s"], "trace": None,
+        "config": cfg, "traffic": traffic, "peaks": peaks,
     }
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": jax.device_count(), "memory_peak_bytes": int(peak)}
@@ -478,8 +483,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         print("trace: " + json.dumps({
             k: tr[k] for k in ("window_s", "busy_s", "idle_share",
                                "exposed_collective_share", "scope_s",
-                               "unscoped_s")} | {"steps": record["steps"]}),
-              file=log)
+                               "nested_s", "unscoped_s")}
+            | {"steps": record["steps"]}), file=log)
     for k, v in numbers.items():
         if k not in checks:
             print(f"reading {k} {v!r} (not compared)", file=log)
@@ -515,20 +520,9 @@ def _peaks(bench_dir: str, kind: str) -> dict:
     return table[kind]
 
 
-def _check_model(model, cfg: dict) -> None:
-    """The program's model has the configuration file's sizes."""
-    z = reference.sizes(cfg)
-    pairs = {
-        "d_model": z["d"], "n_heads": z["h"], "n_kv_heads": z["kv"],
-        "hd": z["hd"], "d_ff": z["ff"], "n_layers": z["layers"],
-        "vocab": z["vocab"], "padded_vocab": z["vocab_rows"],
-        "norm_eps": z["eps"], "rope_theta": z["theta"],
-        "tie_embeddings": cfg["tie_word_embeddings"],
-        "param_dtype": cfg["torch_dtype"], "moe_experts": z["experts"],
-        "moe_topk": z["topk"], "sliding_window": None, "q_head_pad": 0,
-    }
-    if z["experts"]:
-        pairs.update(moe_capacity=z["capacity"], moe_group=z["group"])
+def _check_model(model, pairs: dict) -> None:
+    """The program's model has each attribute of ``pairs`` at its value:
+    the configuration's model module's ``program_fields``."""
     bad = {k: (getattr(model, k), v) for k, v in pairs.items()
            if getattr(model, k) != v}
     if bad:

@@ -4,7 +4,9 @@
 metric. Each has files of its own, found from its name alone:
 
 - a configuration: the ``file`` its entry gives
-  (``bench/configs/<name>.json``);
+  (``bench/configs/<name>.json``), and the model module its file names
+  under ``"reference"`` (``bench/models/<name>.py``; without the key, the
+  model part of ``reference.py``);
 - a traffic mix: ``bench/traffic/<traffic>.json``;
 - a cell's correctness limits: ``bench/limits/<cell>.json``;
 - a metric: its reader, ``bench/metrics/<metric>.py``, which defines
@@ -64,10 +66,26 @@ class Layout:
                 out.append(m)
         return out
 
+    def model(self, cfg: dict):
+        """The model module of a configuration (``reference.py``'s module
+        contract): the file its ``reference`` key names, relative to the
+        checkout's root, else ``reference`` itself."""
+        if "reference" not in cfg:
+            import reference
+            return reference
+        return _load("bench_model_", os.path.join(self.root,
+                                                  cfg["reference"]))
+
     def reader(self, metric: str):
-        path = os.path.join(self.bench_dir, "metrics", f"{metric}.py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load("bench_metric_", os.path.join(
+            self.bench_dir, "metrics", f"{metric}.py")).read
+
+
+def _load(prefix: str, path: str):
+    """The module of the Python file at ``path``, loaded afresh."""
+    name = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

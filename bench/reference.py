@@ -1,12 +1,25 @@
-"""Plain reference of one training job: the model's loss and gradients, the
-GraB balance in full-pytree mode, CD-GraB's coordinated pair balance over W
-workers (a section of its own, below), the Algorithm-3 reorder and AdamW.
+"""Plain reference of one training job, in two parts.
+
+The training reference, the same for every model: the GraB balance in
+full-pytree mode, CD-GraB's coordinated pair balance over W workers (a
+section of its own, below), the Algorithm-3 reorder and AdamW. It takes the
+model's loss from a model module.
+
+A model module gives the model: ``sizes(cfg)``, ``init_params(key, cfg)``,
+``loss(params, tokens, labels, z, prec)`` (z: what ``sizes`` returns),
+``program_fields(cfg)`` (the program's ``ModelConfig`` attributes and the
+values they must have) and optionally ``flops_per_token(cfg, seq_len)``. A
+configuration file names its module under ``"reference"``
+(``bench/models/<name>.py``, loaded by ``Layout.model``); without that key
+the model part of this file is the module. A module may use this file's
+helpers (``_mm``, ``_rms``, ``_rope``, ``hidden_states``, ``head_nll``) and,
+as this file, imports nothing of the program.
 
 Written from the published descriptions in ``jax.numpy``: float32 with every
 matrix product at ``Precision.HIGHEST``, attention as an exact softmax over
 blocks of queries, the mixture of experts as a dense sum over all experts
-weighted by the routed and capacity-limited gates. It imports nothing of the
-program. It reads the sizes from the benchmark's configuration file.
+weighted by the routed and capacity-limited gates. It reads the sizes from
+the benchmark's configuration file.
 
 Parameters are stored as arrays of the configuration's dtype (bfloat16),
 as the program stores them; each step takes the float32 gradient of their
@@ -23,6 +36,7 @@ to float8 e5m2, the next precision below the configuration's bfloat16.
 from __future__ import annotations
 
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +46,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
-# Sizes from the configuration file
+# The default model module: sizes from the configuration file
 # ---------------------------------------------------------------------------
 
 def sizes(cfg: dict) -> dict:
@@ -218,9 +232,9 @@ def _moe(p, x, z, prec):
     return out, aux
 
 
-def loss(params, tokens, labels, z, prec="f32"):
-    """Mean next-token cross-entropy of one sequence (plus the routers'
-    load-balancing term for a mixture of experts)."""
+def hidden_states(params, tokens, z, prec="f32"):
+    """The final-normed hidden states [T, d] of one sequence and the
+    routers' load-balancing terms [layers] (zero for a dense model)."""
     x = params["embed"][tokens].astype(jnp.float32)
     blocks = params["blocks"]
 
@@ -236,8 +250,12 @@ def loss(params, tokens, labels, z, prec="f32"):
         return x + y, aux
 
     x, auxs = jax.lax.scan(layer, x, blocks)
-    x = _rms(x, params["final_norm"]["scale"], z["eps"])
-    head = params["lm_head"][:, :z["vocab"]]
+    return _rms(x, params["final_norm"]["scale"], z["eps"]), auxs
+
+
+def head_nll(x, head, labels, prec="f32"):
+    """Mean next-token cross-entropy of hidden states ``x`` [T, d] under the
+    output head ``head`` [d, vocab], one block of tokens at a time."""
     tb = min(LOSS_BLOCK, x.shape[0])
 
     @jax.checkpoint
@@ -249,20 +267,48 @@ def loss(params, tokens, labels, z, prec="f32"):
 
     per_token = jax.lax.map(nll, (x.reshape(-1, tb, x.shape[1]),
                                   labels.reshape(-1, tb)))
-    return per_token.mean() + z["aux_coef"] * auxs.sum()
+    return per_token.mean()
+
+
+def loss(params, tokens, labels, z, prec="f32"):
+    """Mean next-token cross-entropy of one sequence under the untied
+    ``lm_head`` (plus the routers' load-balancing term for a mixture of
+    experts)."""
+    x, auxs = hidden_states(params, tokens, z, prec)
+    return (head_nll(x, params["lm_head"][:, :z["vocab"]], labels, prec)
+            + z["aux_coef"] * auxs.sum())
+
+
+def program_fields(cfg: dict) -> dict:
+    """The program's ``ModelConfig`` attributes and the values that this
+    model, at the configuration file's sizes, has: the program's windowed
+    attention and padded query heads are not modelled here."""
+    z = sizes(cfg)
+    pairs = {
+        "d_model": z["d"], "n_heads": z["h"], "n_kv_heads": z["kv"],
+        "hd": z["hd"], "d_ff": z["ff"], "n_layers": z["layers"],
+        "vocab": z["vocab"], "padded_vocab": z["vocab_rows"],
+        "norm_eps": z["eps"], "rope_theta": z["theta"],
+        "tie_embeddings": cfg["tie_word_embeddings"],
+        "param_dtype": cfg["torch_dtype"], "moe_experts": z["experts"],
+        "moe_topk": z["topk"], "sliding_window": None, "q_head_pad": 0,
+    }
+    if z["experts"]:
+        pairs.update(moe_capacity=z["capacity"], moe_group=z["group"])
+    return pairs
 
 
 # ---------------------------------------------------------------------------
 # Training: accumulate, balance, update
 # ---------------------------------------------------------------------------
 
-def _microbatch(z, prec, grab):
-    """One microbatch: its gradient, the GraB balance against the running
-    sum (Algorithm 5: +1 iff <s, g - m_prev> <= 0, with m_prev zero in the
-    first epoch) and the accumulation."""
+def _microbatch(z, prec, grab, loss_fn):
+    """One microbatch: its gradient under the model's ``loss_fn``, the GraB
+    balance against the running sum (Algorithm 5: +1 iff <s, g - m_prev> <=
+    0, with m_prev zero in the first epoch) and the accumulation."""
     def f(params, acc, s, tokens, labels):
         def mean_loss(p):
-            return jax.vmap(lambda a, b: loss(p, a, b, z, prec))(
+            return jax.vmap(lambda a, b: loss_fn(p, a, b, z, prec))(
                 tokens, labels).mean()
 
         p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
@@ -309,8 +355,9 @@ def leaf_norms(tree) -> np.ndarray:
 
 
 def train_steps(init, steps, cfg: dict, hp: dict, *, grab: bool,
-                prec: str = "f32", keep: float = 1.0) -> dict:
-    """Run the first optimizer steps from the parameters ``init()`` makes.
+                prec: str = "f32", keep: float = 1.0, model=None) -> dict:
+    """Run the first optimizer steps of the model module ``model`` (this
+    file's own model where None) from the parameters ``init()`` makes.
 
     ``steps``: list of (tokens, labels) pairs, each ``[n_micro, micro, T]``.
     ``keep`` < 1 uses only the first share of each step's microbatches (a
@@ -319,8 +366,9 @@ def train_steps(init, steps, cfg: dict, hp: dict, *, grab: bool,
     gradient (``grad_raw``) and of the same clipped, as AdamW takes it
     (``grad``); of the parameters' change over all steps (``update``); and,
     with ``grab``, of the running sum (``sum``)."""
-    z = sizes(cfg)
-    micro = _microbatch(z, prec, grab)
+    model = model or sys.modules[__name__]
+    z = model.sizes(cfg)
+    micro = _microbatch(z, prec, grab, model.loss)
     adamw = _adamw(hp)
     zeros = jax.jit(lambda: jax.tree.map(
         lambda x: jnp.zeros(x.shape, jnp.float32), jax.eval_shape(init)))
@@ -486,8 +534,8 @@ def worker_mesh(devices, workers: int):
     return Mesh(np.array(devices[:n]), ("w",))
 
 
-def _timestep(z, prec, idx, mesh):
-    """One timestep of the W workers: each worker's loss and float32
+def _timestep(z, prec, idx, mesh, loss_fn):
+    """One timestep of the W workers: each worker's ``loss_fn`` and float32
     gradient on its microbatch, the sketch of that gradient, and its
     gradient added, times the worker's weight (1, or 0 where a planted
     fault leaves it out), into its device's partial sum. Workers are split
@@ -501,7 +549,7 @@ def _timestep(z, prec, idx, mesh):
             tok, lab, wt = xs
 
             def mean_loss(p):
-                return jax.vmap(lambda t, l: loss(p, t, l, z, prec))(
+                return jax.vmap(lambda t, l: loss_fn(p, t, l, z, prec))(
                     tok, lab).mean()
 
             val, g = jax.value_and_grad(mean_loss)(p32)
@@ -521,9 +569,10 @@ def _timestep(z, prec, idx, mesh):
 def train_steps_cd(init, steps, cfg: dict, hp: dict, *, workers: int,
                    sketch_dim: int, devices, prog_signs=None,
                    prec: str = "f32", keep: float = 1.0,
-                   drop_row=None, flip: bool = False) -> dict:
-    """The first optimizer steps of CD-GraB with W = ``workers``, from the
-    parameters ``init()`` makes.
+                   drop_row=None, flip: bool = False, model=None) -> dict:
+    """The first optimizer steps of CD-GraB with W = ``workers``, of the
+    model module ``model`` as in :func:`train_steps`, from the parameters
+    ``init()`` makes.
 
     ``steps``: list of (tokens, labels), each ``[n_micro, micro, T]`` in
     the time-major order (unit j of a step is worker j % W's at timestep
@@ -541,14 +590,15 @@ def train_steps_cd(init, steps, cfg: dict, hp: dict, *, workers: int,
     signs that a run forced to those would."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    z = sizes(cfg)
+    model = model or sys.modules[__name__]
+    z = model.sizes(cfg)
     mesh = worker_mesh(devices, workers)
     repl = NamedSharding(mesh, P())
     rows_sh = NamedSharding(mesh, P("w"))
     shapes = [x.shape for x in jax.tree.leaves(jax.eval_shape(init))]
     idx = [None if i is None else jnp.asarray(i)
            for i in sketch_indices(shapes, sketch_dim)]
-    step_t = _timestep(z, prec, idx, mesh)
+    step_t = _timestep(z, prec, idx, mesh, model.loss)
     adamw = _adamw(hp)
     zeros = jax.jit(lambda: jax.tree.map(
         lambda x: jnp.zeros(x.shape, jnp.float32), jax.eval_shape(init)),

@@ -59,8 +59,8 @@ def test_ops_take_the_op_name_of_the_program_they_run_in():
            (210, 220, "%copy.2 = f32[8]{0} copy(f32[8]{0} %p)"),
            (150, 160, "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)")]
     got = [e[3:] for e in ts.scope_events(ops, programs, op_names)]
-    assert got == [("grab_balance", False), (None, False),
-                   ("grab_rollover", False), (None, None)]
+    assert got == [("grab_balance", False, ()), (None, False, ()),
+                   ("grab_rollover", False, ()), (None, None, ())]
 
 
 def _varint(x: int) -> bytes:
@@ -195,6 +195,91 @@ def test_whole_trace_as_window():
     out = ts.reduce_events(ev, None)
     assert out["window_s"] == pytest.approx(0.100)     # the spans' extent
     assert out["scope_s"]["fwd_bwd"] == pytest.approx(0.030)
+
+
+@pytest.mark.parametrize("op_name, scope, nested", [
+    ("jit(train_step)/while/body/fwd_bwd/jvp()/while/body/closed_call/"
+     "moe_experts/dot_general", "fwd_bwd",
+     ("jvp", "while", "body", "closed_call", "moe_experts")),
+    ("jit(train_step)/transpose(jvp(fwd_bwd))/moe_experts/router/dot_general",
+     "fwd_bwd", ("moe_experts", "router")),
+    ("jit(train_step)/while/body/fwd_bwd/jit(fwd_bwd)/moe_experts/"
+     "jit(moe_experts)/dot", "fwd_bwd", ("jit", "moe_experts")),
+    ("jit(train_step)/grab_balance/fold/add", "grab_balance", ("fold",)),
+    ("jit(train_step)/optimizer/sqrt", "optimizer", ()),
+    ("jit(grab_rollover)/grab_rollover/broadcast_in_dim", "grab_rollover",
+     ()),
+    ("jit(train_step)/while/body/add", None, ()),
+])
+def test_nested_words(op_name, scope, nested):
+    assert ts.scope_of(op_name) == scope
+    assert ts.nested_words(op_name, scope) == nested
+
+
+def _nested_events():
+    """One device, one program, window [0, 100] ms: under ``fwd_bwd`` an
+    expert op forward (0-20) and one transposed (20-30), both nested in
+    ``moe_experts``, the second also in ``router``, an attention op (30-40)
+    and a loop container (0-30); under ``grab_balance`` an op nested in
+    ``fold`` and ``moe_experts`` (40-50); an optimizer op (50-60); an
+    unnamed copy (60-65)."""
+    program = "jit_train_step(1)"
+    names = {
+        "while.1": "jit(train_step)/while/body/fwd_bwd/jvp()/while",
+        "fusion.2": "jit(train_step)/while/body/fwd_bwd/jvp()/while/body/"
+                    "closed_call/moe_experts/dot_general",
+        "fusion.3": "jit(train_step)/transpose(jvp(fwd_bwd))/moe_experts/"
+                    "router/jit(moe_experts)/dot_general",
+        "fusion.4": "jit(train_step)/while/body/fwd_bwd/attn/dot_general",
+        "fusion.5": "jit(train_step)/grab_balance/fold/moe_experts/add",
+        "fusion.6": "jit(train_step)/optimizer/mul",
+    }
+    hlo = {program: {n: ts.HloOp(o, False) for n, o in names.items()}}
+    hlo[program]["copy.7"] = ts.HloOp(None, False)
+    ops = [(0, 30 * MS, "while.1"), (0, 20 * MS, "fusion.2"),
+           (20 * MS, 30 * MS, "fusion.3"), (30 * MS, 40 * MS, "fusion.4"),
+           (40 * MS, 50 * MS, "fusion.5"), (50 * MS, 60 * MS, "fusion.6"),
+           (60 * MS, 65 * MS, "copy.7")]
+    events = ts.scope_events(ops, [(0, 100 * MS, program)], hlo)
+    return {"devices": {"/device:TPU:0": sorted(events)},
+            "spans": [(0, 100 * MS, "window")]}
+
+
+def test_nested_seconds_beside_the_scopes():
+    out = ts.reduce_events(_nested_events(), "window")
+    assert out["scope_s"] == {"fwd_bwd": pytest.approx(0.040),
+                              "grab_balance": pytest.approx(0.010),
+                              "optimizer": pytest.approx(0.010)}
+    assert out["unscoped_s"] == pytest.approx(0.005)
+    nested = out["nested_s"]
+    # each word once an op, the container left out
+    assert nested["moe_experts"] == pytest.approx(0.040)
+    assert nested["router"] == pytest.approx(0.010)
+    assert nested["attn"] == pytest.approx(0.010)
+    assert nested["fold"] == pytest.approx(0.010)
+    assert nested["jvp"] == pytest.approx(0.020)
+    assert not {"fwd_bwd", "grab_balance", "optimizer", "dot_general",
+                "add", "mul"} & set(nested)
+
+
+def test_nested_seconds_leave_the_other_numbers_as_they_were():
+    """The same ops with their nested words dropped give the same scope
+    seconds, unscoped seconds, ops and gaps, and no nested seconds."""
+    ev = _nested_events()
+    plain = {"devices": {p: [e[:5] for e in evs]
+                         for p, evs in ev["devices"].items()},
+             "spans": ev["spans"]}
+    new, old = ts.reduce_events(ev, "window"), ts.reduce_events(plain,
+                                                                "window")
+    assert old.pop("nested_s") == {}
+    new.pop("nested_s")
+    assert new == old
+
+
+def test_recorded_cpu_trace_nested_and_scopes_as_before():
+    out = ts.reduce_file(FIXTURE, "bench_window")
+    assert out["nested_s"] == {}
+    assert out["scope_s"] == {} and out["unscoped_s"] == 0.0
 
 
 def test_recorded_cpu_trace():

@@ -13,6 +13,16 @@ the program it runs in, where the program is named after a scope
 (``jit_grab_rollover``); else it is unscoped. A fusion counts under its
 own metadata.
 
+Scopes nested inside those five, such as a model's expert layer inside
+``fwd_bwd``, are counted beside them (``nested_s``): an op's time goes to
+each distinct word of its ``op_name`` after its scope, in the path
+components before the last (the operation's own name) and after the scope
+inside the scope's own component (``jit(fwd_bwd)/moe_experts/dot``,
+``transpose(jvp(fwd_bwd))/moe_experts/dot``: ``moe_experts``). The words
+are those of the name stack, so wrappers (``jvp``, ``transpose``) and
+control flow (``while``, ``body``) are among them; a reader looks up the
+scope it wants.
+
 The loop also nests host spans in ``epoch_reorder`` (``sign_fetch``,
 ``reorder``, ``rollover``) and opens ``epoch_hook``; an idle gap here is
 labelled ``parent/child`` when a span nested in the span that overlaps the
@@ -25,9 +35,9 @@ This builds on ``trace_reduce`` and changes none of its numbers.
 ``TRACE`` is an ``.xplane.pb`` file or a directory holding one, such as
 the ``--profile-dir`` of ``examples/train_lm.py --profile-steps A:B``.
 Without ``--window`` the whole trace is the window. Prints one JSON
-object: the window, busy time, device seconds per scope and unscoped,
-the steps (``dispatch`` spans) in the window, the top ops named
-``<scope>/<op>`` and the idle gaps with their labels.
+object: the window, busy time, device seconds per scope, nested in a
+scope and unscoped, the steps (``dispatch`` spans) in the window, the top
+ops named ``<scope>/<op>`` and the idle gaps with their labels.
 """
 from __future__ import annotations
 
@@ -64,6 +74,19 @@ def scope_of(op_name) -> str | None:
             if word in SCOPES:
                 return word
     return None
+
+
+def nested_words(op_name, scope) -> tuple:
+    """The distinct words of ``op_name`` after its first ``scope`` and
+    before its last path component, ``scope`` itself left out."""
+    parts = (op_name or "").split("/")
+    for i, part in enumerate(parts):
+        words = _WORD.findall(part)
+        if scope in words:
+            after = words[words.index(scope) + 1:] + [
+                w for p in parts[i + 1:-1] for w in _WORD.findall(p)]
+            return tuple(dict.fromkeys(w for w in after if w != scope))
+    return ()
 
 
 def program_scope(program: str) -> str | None:
@@ -195,12 +218,13 @@ def _module_ops(module) -> dict:
 
 
 def scope_events(ops, programs, hlo):
-    """``(start, end, name, scope, collective)`` of each device op
+    """``(start, end, name, scope, collective, nested)`` of each device op
     ``(start, end, name)``: the op's program is the ``XLA Modules`` event
     ``(start, end, program)`` it runs in, its ``op_name`` and whether it is
     a collective that program's (``hlo_ops``; None where the trace carries
-    no HLO of it), and its scope the first of ``SCOPES`` in the
-    ``op_name``, else the program's own (``program_scope``)."""
+    no HLO of it), its scope the first of ``SCOPES`` in the ``op_name``,
+    else the program's own (``program_scope``), and ``nested`` the words
+    of the ``op_name`` nested in that scope (``nested_words``)."""
     programs = sorted(programs)
     starts = [p[0] for p in programs]
     out = []
@@ -208,16 +232,19 @@ def scope_events(ops, programs, hlo):
         i = bisect.bisect_right(starts, a) - 1
         program = programs[i][2] if i >= 0 and a < programs[i][1] else None
         op = hlo.get(program, {}).get(tr.op_name(name))
-        out.append((a, b, name,
-                    scope_of(op and op.op_name) or program_scope(program),
-                    op and op.collective))
+        op_name = op and op.op_name
+        scope = scope_of(op_name)
+        nested = nested_words(op_name, scope) if scope else ()
+        out.append((a, b, name, scope or program_scope(program),
+                    op and op.collective, nested))
     return out
 
 
 def read_events(path: str, span_names) -> dict:
-    """``{"devices": {plane: [(start, end, name, scope)]}, "spans": [(start,
-    end, name)]}``: each chip's device ops with the scope of each, and the
-    host spans restricted to ``span_names``."""
+    """``{"devices": {plane: [(start, end, name, scope, collective,
+    nested)]}, "spans": [(start, end, name)]}``: each chip's device ops as
+    ``scope_events`` gives them, and the host spans restricted to
+    ``span_names``."""
     from jax.profiler import ProfileData
 
     with open(path, "rb") as f:
@@ -261,10 +288,12 @@ def nested_label(spans, a: float, b: float, skip: str) -> str:
 
 
 def reduce_events(ev: dict, window, top: int = 10) -> dict:
-    """Device seconds per scope inside the ``window`` span (the whole
-    trace when ``window`` is None), averaged over the chips, with
-    control-flow containers left out as ``trace_reduce.reduce_events``
-    leaves them out of ``device_ops``."""
+    """Device seconds per scope (``scope_s``) and per word nested in a
+    scope (``nested_s``) inside the ``window`` span (the whole trace when
+    ``window`` is None), averaged over the chips, with control-flow
+    containers left out as ``trace_reduce.reduce_events`` leaves them out
+    of ``device_ops``. An event may leave out ``collective`` and
+    ``nested``."""
     if window is None:
         ends = [e[:2] for evs in ev["devices"].values() for e in evs]
         ends += [s[:2] for s in ev["spans"]]
@@ -272,12 +301,13 @@ def reduce_events(ev: dict, window, top: int = 10) -> dict:
     else:
         lo, hi = tr.window_of(ev["spans"], window)
     scope_t, op_t = defaultdict(float), defaultdict(float)
+    nested_t = defaultdict(float)
     unscoped, busy, gap_list = 0.0, 0.0, []
     for plane, events in sorted(ev["devices"].items()):
         merged = tr.merge(events, lo, hi)
         busy += tr.length(merged)
         gap_list.extend(tr.gaps(merged, lo, hi))
-        for a, b, name, scope, *_ in events:
+        for a, b, name, scope, *rest in events:
             name = tr.op_name(name)
             d = min(b, hi) - max(a, lo)
             if d <= 0 or name.startswith(tr.CONTAINERS):
@@ -288,6 +318,8 @@ def reduce_events(ev: dict, window, top: int = 10) -> dict:
             else:
                 scope_t[scope] += d
                 op_t[f"{scope}/{name}"] += d
+                for word in rest[1] if len(rest) > 1 else ():
+                    nested_t[word] += d
     n_dev = max(len(ev["devices"]), 1)
     gap_list.sort(key=lambda g: g[0] - g[1])
     return {
@@ -296,6 +328,8 @@ def reduce_events(ev: dict, window, top: int = 10) -> dict:
         "steps": sum(1 for a, b, n in ev["spans"]
                      if n == "dispatch" and a >= lo and b <= hi),
         "scope_s": {s: t / n_dev / 1e9 for s, t in sorted(scope_t.items())},
+        "nested_s": {w: t / n_dev / 1e9
+                     for w, t in sorted(nested_t.items())},
         "unscoped_s": unscoped / n_dev / 1e9,
         "device_ops": [[name, t / n_dev / 1e9] for name, t in sorted(
             op_t.items(), key=lambda kv: -kv[1])[:top]],
