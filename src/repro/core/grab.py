@@ -6,18 +6,30 @@ Device side
 :class:`GrabState` carries O(d) state (three gradient-shaped pytrees) and
 :func:`grab_step` implements lines 6-12 of Algorithm 4 for one stochastic
 gradient: center with the *stale mean* ``m_prev``, pick a sign with the
-balancer, update the running signed sum ``s`` and the fresh-mean accumulator
+balancer, update the running signed sum and the fresh-mean accumulator
 ``m_acc``. It is jit-safe and sharding-transparent: all three pytrees share
 the gradient's PartitionSpecs, so the balancing inner product lowers to
 per-shard partial dots + one scalar all-reduce.
 
+In full-pytree mode the running sum is kept uncentered: ``s`` holds
+``S = sum_i eps_i g_i`` and the scalar ``e`` holds ``E = sum_i eps_i``, so
+the algorithm's centered sum ``sum_i eps_i (g_i - m_prev)`` is
+``S - E m_prev`` (:func:`grab_running_sum`). The sign's inner product
+``<S - E m_prev, g - m_prev>`` still reads ``s``, ``g`` and ``m_prev``, but
+the update ``S += eps g`` no longer reads ``m_prev``: one f32 read of the
+gradient's size less per balanced gradient. The values are the same up to
+f32 rounding; the cancellation in ``S - E m_prev`` costs at most
+``log2 |E|`` bits, and ``|E|`` stays small because the balancer balances
+the signs (the loop's ``grab.sign_imbalance`` gauge). Sketch mode and pair
+balancing keep the centered ``s``, and their ``e`` stays 0.
+
 ``grab_step`` is two halves that callers may also run apart:
-:func:`grab_balance_step` (center, sign, update ``s``, advance ``t``) and
-:func:`grab_fold_mean` (``m_acc + grad_sum / n_per_epoch``). The fresh mean
-is a plain sum, so it need not be folded per gradient: the train step
-balances every microbatch but folds its f32 gradient sum into ``m_acc`` once
-per optimizer step, after the microbatch scan, which keeps ``m_acc`` out of
-the scan's per-microbatch reads and writes.
+:func:`grab_balance_step` (center, sign, update ``s`` and ``e``, advance
+``t``) and :func:`grab_fold_mean` (``m_acc + grad_sum / n_per_epoch``). The
+fresh mean is a plain sum, so it need not be folded per gradient: the train
+step balances every microbatch but folds its f32 gradient sum into ``m_acc``
+once per optimizer step, after the microbatch scan, which keeps ``m_acc``
+out of the scan's per-microbatch reads and writes.
 
 Sketch mode (beyond the paper) keeps ``s`` only for a fixed coordinate
 subsample of the gradient (``k`` entries), cutting balance state and the
@@ -46,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.balance import alweiss_sign, deterministic_sign, tree_balance_step
-from repro.utils.tree import tree_zeros_like
+from repro.utils.tree import tree_dot, tree_zeros_like
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +84,12 @@ class GrabConfig:
 
 
 class GrabState(NamedTuple):
-    s: Any            # running signed sum (pytree, or [k] vector in sketch mode)
+    s: Any            # running signed sum: full-pytree mode the uncentered
+                      # sum of eps * g (pytree; grab_running_sum centers it),
+                      # sketch mode and pair balancing the centered sum
+                      # (a [k] vector in sketch mode)
+    e: jax.Array      # f32 sum of the epoch's signs (full-pytree mode; 0
+                      # in sketch mode and pair balancing)
     m_prev: Any       # stale mean from previous epoch (pytree)
     m_acc: Any        # fresh mean accumulator (pytree): the epoch's gradients
                       # so far over n_per_epoch, folded in once per train step
@@ -174,9 +191,19 @@ def init_grab_state(grad_template, cfg: GrabConfig) -> GrabState:
         s = jnp.zeros((cfg.sketch_dim,), jnp.float32)
     else:
         s = tree_zeros_like(grad_template, jnp.float32)
-    return GrabState(s=s, m_prev=tree_zeros_like(grad_template, jnp.float32),
+    return GrabState(s=s, e=jnp.float32(0),
+                     m_prev=tree_zeros_like(grad_template, jnp.float32),
                      m_acc=tree_zeros_like(grad_template, jnp.float32),
                      t=jnp.int32(0), key=jax.random.PRNGKey(cfg.seed))
+
+
+def grab_running_sum(state: GrabState, cfg: GrabConfig):
+    """The algorithm's centered running sum ``sum_i eps_i (g_i - m_prev)``:
+    ``s - e * m_prev`` leaf by leaf in full-pytree mode, ``s`` itself in
+    sketch mode and pair balancing."""
+    if cfg.sketch_dim > 0 or cfg.pair_balance:
+        return state.s
+    return jax.tree.map(lambda s, m: s - state.e * m, state.s, state.m_prev)
 
 
 def grab_step(state: GrabState, grad, n_per_epoch: int, cfg: GrabConfig,
@@ -194,33 +221,40 @@ def grab_step(state: GrabState, grad, n_per_epoch: int, cfg: GrabConfig,
 def grab_balance_step(state: GrabState, grad, cfg: GrabConfig,
                       sketch: Optional[Sketch] = None):
     """The balance half of :func:`grab_step`: center ``grad`` with the stale
-    mean, pick its sign, add the signed centered gradient to ``s`` and
-    advance ``t``. ``m_acc`` is left as it is (it may be None); the caller
-    folds the gradient in with :func:`grab_fold_mean`. Returns (new_state,
-    eps in {-1,+1})."""
+    mean, pick its sign from its inner product with the centered running
+    sum, add the signed gradient to the running sum and advance ``t``.
+    Full-pytree mode adds ``eps * grad`` to ``s`` and ``eps`` to ``e`` (see
+    :func:`grab_running_sum`); sketch mode adds the signed sketched centered
+    gradient to ``s``. ``m_acc`` is left as it is (it may be None); the
+    caller folds the gradient in with :func:`grab_fold_mean`. Returns
+    (new_state, eps in {-1,+1})."""
     assert not cfg.pair_balance, "pair balancing stashes in m_acc: grab_step"
     g32 = jax.tree.map(lambda x: x.astype(jnp.float32), grad)
     centered = jax.tree.map(jnp.subtract, g32, state.m_prev)
 
-    key = state.key
     if cfg.sketch_dim > 0:
         assert sketch is not None, "sketch mode needs a Sketch"
         z = sketch.apply(centered)
-        dot = jnp.vdot(state.s, z)
-        if cfg.balancer == "deterministic":
-            eps = deterministic_sign(dot)
-        else:
-            key, sub = jax.random.split(key)
-            eps = alweiss_sign(dot, jnp.float32(cfg.alweiss_c), sub)
-        new_s = state.s + eps.astype(jnp.float32) * z
-    else:
-        if cfg.balancer == "alweiss":
-            key, sub = jax.random.split(key)
-            new_s, eps = tree_balance_step(state.s, centered, kind="alweiss",
-                                           c=cfg.alweiss_c, key=sub)
-        else:
-            new_s, eps = tree_balance_step(state.s, centered)
-    return state._replace(s=new_s, t=state.t + 1, key=key), eps
+        eps, key = _pick_sign(jnp.vdot(state.s, z), cfg, state.key)
+        return state._replace(s=state.s + eps.astype(jnp.float32) * z,
+                              t=state.t + 1, key=key), eps
+    # one reduction over s, g and m_prev per leaf: the centered sum is
+    # formed inside it, never stored
+    dot = tree_dot(grab_running_sum(state, cfg), centered)
+    eps, key = _pick_sign(dot, cfg, state.key)
+    epsf = eps.astype(jnp.float32)
+    new_s = jax.tree.map(lambda s, g: s + epsf * g, state.s, g32)
+    return state._replace(s=new_s, e=state.e + epsf, t=state.t + 1,
+                          key=key), eps
+
+
+def _pick_sign(dot, cfg: GrabConfig, key):
+    """The balancer's sign for the inner product ``dot``; Alweiss splits
+    ``key``. Returns (eps, key)."""
+    if cfg.balancer == "deterministic":
+        return deterministic_sign(dot), key
+    key, sub = jax.random.split(key)
+    return alweiss_sign(dot, jnp.float32(cfg.alweiss_c), sub), key
 
 
 def grab_fold_mean(m_acc, grad_sum, n_per_epoch: int):
@@ -294,7 +328,7 @@ def init_parallel_grab_state(grad_template, cfg: GrabConfig,
         s = jnp.zeros((cfg.sketch_dim,), jnp.float32)
     else:
         s = tree_zeros_like(grad_template, jnp.float32)
-    return GrabState(s=s, m_prev=stash(), m_acc=stash(),
+    return GrabState(s=s, e=jnp.float32(0), m_prev=stash(), m_acc=stash(),
                      t=jnp.int32(0), key=jax.random.PRNGKey(cfg.seed))
 
 
@@ -457,13 +491,13 @@ def expand_pair_signs(signs: np.ndarray) -> np.ndarray:
 
 
 def grab_epoch_end(state: GrabState, cfg: GrabConfig) -> GrabState:
-    """Promote the fresh mean to stale, reset the sum and accumulator."""
+    """Promote the fresh mean to stale, reset the sums and accumulator."""
     if cfg.sketch_dim > 0:
         s = jnp.zeros_like(state.s)
     else:
         s = tree_zeros_like(state.s, jnp.float32)
     m_prev = (tree_zeros_like(state.m_acc, jnp.float32) if cfg.pair_balance
               else state.m_acc)
-    return GrabState(s=s, m_prev=m_prev,
+    return GrabState(s=s, e=jnp.float32(0), m_prev=m_prev,
                      m_acc=tree_zeros_like(state.m_acc, jnp.float32),
                      t=jnp.int32(0), key=state.key)

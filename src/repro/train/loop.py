@@ -37,7 +37,10 @@ are named scopes (``train/step.py``) and the rollover runs under
 ``grab_rollover``. Per-epoch ordering-quality metrics are computed from the
 sign buffer's existing once-per-epoch fetch, the compiled step's device
 bytes are ``step.*_bytes`` gauges, the GraB state's bytes per chip the
-``grab.state_bytes`` gauge, and everything lands in one schema-validated
+``grab.state_bytes`` gauge, the epoch's |sum of signs| over its number of
+signs the ``grab.sign_imbalance`` gauge (set once an epoch from the fetched
+buffer; in full-pytree mode it bounds the f32 cancellation in the running
+sum ``s - e * m_prev``), and everything lands in one schema-validated
 JSONL run log (``LoopConfig.metrics_out``) — recording never adds a
 device→host sync (enforced by the transfer-guarded
 ``tests/test_async_loop.py``).
@@ -395,8 +398,9 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
                         state.grab, grab_cfg=grab_cfg))
             # zero-sync ordering quality: numpy over the buffer the reorder
             # already fetched — never an extra transfer
-            reg.emit("quality", epoch=epoch,
-                     **ordering_quality(raw_signs, grab_cfg.pair_balance))
+            quality = ordering_quality(raw_signs, grab_cfg.pair_balance)
+            reg.emit("quality", epoch=epoch, **quality)
+            reg.gauge("grab.sign_imbalance").set(quality["imbalance"])
         flush_losses()
         if manager:
             with phase("ckpt_save", reg):

@@ -5,16 +5,23 @@ and scans over the microbatch axis:
 
     for t in range(n_micro):                       # lax.scan
         g_t   = grad(loss)(params, micro_t)        # needed for accumulation anyway
-        state, eps_t = grab_balance_step(state, g_t)  # dot + sign + axpy
-        acc  += g_t
+        # grab_balance_step:
+        eps_t = sign(<s - e * m_prev, g_t - m_prev>)  # one read of s, g, m_prev
+        s    += eps_t * g_t;  e += eps_t           # no m_prev here
+        acc  += g_t                                # fused with the s update
     state.m_acc = grab_fold_mean(state.m_acc, acc)    # once per step
 
 so GraB's ordering signal costs **zero extra gradient computations** — the
 paper's §6 gradient-accumulation workaround as a first-class systems feature.
+``s`` is the uncentered sum of signed gradients and ``e`` the sum of signs;
+``s - e * m_prev`` is the algorithm's centered running sum
+(``grab.grab_running_sum``), formed inside the inner product and never
+stored, so only the inner product reads the stale mean.
 The fresh mean ``m_acc`` is a sum the accumulator ``acc`` already holds, so
 it stays out of the scan: folding it per microbatch would read and write one
 more f32 tree every microbatch instead of once a step. (Pair balancing keeps
-its pair stash in ``m_acc`` and runs ``grab_step`` whole inside the scan.)
+its pair stash in ``m_acc``, and a centered ``s``, and runs ``grab_step``
+whole inside the scan.)
 The per-microbatch signs come back to the host, which reorders the global
 microbatch permutation for the next epoch (Algorithm 3 two-pointer).
 

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.balance import balance_sequence
-from repro.core.grab import (GrabConfig, grab_epoch_end, grab_step,
-                             init_grab_state, make_sketch)
+from repro.core.grab import (GrabConfig, grab_epoch_end, grab_running_sum,
+                             grab_step, init_grab_state, make_sketch)
 from repro.core.herding import reorder_from_signs
 
 
@@ -85,6 +85,49 @@ def test_alweiss_grab_runs():
     g = _tree(np.random.default_rng(2).normal(size=16).astype(np.float32))
     st, eps = grab_step(st, g, 4, cfg)
     assert int(eps) in (-1, 1)
+
+
+@pytest.mark.parametrize("mode", ["sketch", "pair", "pair-sketch"])
+def test_sketch_and_pair_modes_keep_the_centered_sum(mode):
+    """Sketch mode and pair balancing keep the centered running sum: after
+    a few steps (a nonzero stale mean in sketch mode) ``s`` is bit for bit
+    the centered recurrence ``s += eps z`` with ``eps`` from ``<s, z>``, ``z``
+    the sketched centered gradient or the pair's difference, and ``e``
+    stays 0, so ``grab_running_sum`` is ``s`` itself."""
+    from repro.utils.tree import tree_dot
+
+    cfg = GrabConfig(sketch_dim=6 if "sketch" in mode else 0,
+                     pair_balance="pair" in mode)
+    rng = np.random.default_rng(4)
+    tmpl = _tree(np.zeros(16, np.float32))
+    sk = make_sketch(tmpl, 6, seed=0) if cfg.sketch_dim else None
+    st = init_grab_state(tmpl, cfg)
+    if not cfg.pair_balance:
+        st = st._replace(m_prev=_tree(rng.normal(size=16).astype(np.float32)))
+    ref_s, stash = st.s, None
+    for t in range(6):
+        g = _tree(rng.normal(size=16).astype(np.float32))
+        st, eps = grab_step(st, g, n_per_epoch=6, cfg=cfg, sketch=sk)
+        if cfg.pair_balance and t % 2 == 0:
+            stash = g
+            assert int(eps) == 0
+            continue
+        z = (jax.tree.map(jnp.subtract, stash, g) if cfg.pair_balance
+             else jax.tree.map(jnp.subtract, g, st.m_prev))
+        if sk is not None:
+            z = sk.apply(z)
+            dot = jnp.vdot(ref_s, z)
+        else:
+            dot = tree_dot(ref_s, z)
+        want = 1 if float(dot) <= 0 else -1
+        assert int(eps) == want
+        ref_s = jax.tree.map(lambda a, b: a + jnp.float32(want) * b, ref_s, z)
+    for a, b in zip(jax.tree.leaves(st.s), jax.tree.leaves(ref_s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(st.e) == 0.0
+    for a, b in zip(jax.tree.leaves(grab_running_sum(st, cfg)),
+                    jax.tree.leaves(st.s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ CPU's rewrites of reductions) carry no ``op_name`` at all; the program
 cannot name them, and a chip trace reads their time as unscoped.
 """
 import collections
-import re
 
 import jax
 import jax.numpy as jnp
@@ -26,61 +25,11 @@ from repro.models import lm
 from repro.optim import adamw, constant
 from repro.train.loop import grab_rollover
 from repro.train.step import build_train_step, init_train_state
+from _hlo import hlo_bytes, instructions, microbatch_loop, shape_dims
 
-SCOPES = ("fwd_bwd", "grab_balance", "grad_accum", "optimizer",
-          "grab_rollover")
 # instructions that only route buffers or hold a loop's body
 PLUMBING = ("parameter", "get-tuple-element", "tuple", "constant", "bitcast",
             "while", "call", "conditional")
-_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
-                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
-                "u64": 8}
-_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
-_COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
-
-
-def _scope(op_name):
-    for part in (op_name or "").split("/"):
-        for word in re.findall(r"[A-Za-z0-9_]+", part):
-            if word in SCOPES:
-                return word
-    return None
-
-
-def _bytes(shape: str) -> int:
-    total = 0
-    for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", shape):
-        if dt in _DTYPE_BYTES:
-            total += int(np.prod([int(d) for d in dims.split(",") if d])) \
-                * _DTYPE_BYTES[dt]
-    return total
-
-
-def _instructions(hlo: str) -> list:
-    """Every instruction as a dict: its computation, whether that is a
-    fusion's body or the entry, name, shape, opcode, ``op_name``, the
-    computations it calls and, for a loop, its condition and body."""
-    out, comp, entry = [], None, False
-    for line in hlo.splitlines():
-        m = _COMP.match(line)
-        if m:
-            comp, entry = m.group(1), line.startswith("ENTRY")
-            continue
-        m = _INSTR.match(line)
-        if m and comp is not None:
-            op = re.search(r'op_name="([^"]*)"', line)
-            out.append({"comp": comp, "entry": entry, "name": m.group(1),
-                        "shape": m.group(2), "opcode": m.group(3),
-                        "op_name": op.group(1) if op else None,
-                        "calls": re.findall(
-                            r"(?:calls|to_apply)=%?([\w.\-]+)", line),
-                        "loops": re.findall(
-                            r"(?:condition|body)=%?([\w.\-]+)", line)})
-    fused = {c for i in out if i["opcode"] == "fusion" for c in i["calls"]}
-    for i in out:
-        i["fused"] = i["comp"] in fused
-        i["scope"] = _scope(i["op_name"])
-    return out
 
 
 def _tiny_lm():
@@ -103,12 +52,7 @@ def step_hlo(request):
     leaf_shapes = collections.Counter(
         tuple(x.shape) for x in jax.tree.leaves(params))
     smallest = min(x.size * 4 for x in jax.tree.leaves(params))
-    return request.param, _instructions(hlo), leaf_shapes, smallest
-
-
-def _shape_dims(shape: str):
-    m = re.match(r"([a-z]+\d*)\[([\d,]*)\]", shape)
-    return m.group(1), tuple(int(d) for d in m.group(2).split(",") if d)
+    return request.param, instructions(hlo), leaf_shapes, smallest
 
 
 def test_model_dots_fall_under_fwd_bwd(step_hlo):
@@ -116,7 +60,7 @@ def test_model_dots_fall_under_fwd_bwd(step_hlo):
     recompute included), is under ``fwd_bwd``."""
     _, ins, _, _ = step_hlo
     dots = [i for i in ins if i["opcode"] == "dot"
-            and len(_shape_dims(i["shape"])[1]) >= 2]
+            and len(shape_dims(i["shape"])[1]) >= 2]
     assert dots
     assert {i["scope"] for i in dots} == {"fwd_bwd"}, \
         [(i["name"], i["op_name"]) for i in dots if i["scope"] != "fwd_bwd"]
@@ -134,9 +78,9 @@ def test_balance_falls_under_grab_balance(step_hlo):
         assert bal == []
         return
     assert any(i["opcode"] == "reduce" for i in bal)
-    # per parameter leaf, the f32 updates of s (s + eps * z) and of m_acc
+    # per parameter leaf, the f32 updates of s (s + eps * g) and of m_acc
     # (m_acc + g / n): two adds of the leaf's shape
-    adds = [_shape_dims(i["shape"]) for i in bal if i["opcode"] == "add"]
+    adds = [shape_dims(i["shape"]) for i in bal if i["opcode"] == "add"]
     for shape in leaf_shapes:
         assert adds.count(("f32", shape)) >= 2, (shape, adds)
 
@@ -172,13 +116,46 @@ def test_fresh_mean_folds_once_after_the_microbatch_loop(step_hlo):
 
     def balance_adds(comps):
         return collections.Counter(
-            _shape_dims(i["shape"])[1] for i in ins
+            shape_dims(i["shape"])[1] for i in ins
             if i["comp"] in comps and i["scope"] == "grab_balance"
-            and i["opcode"] == "add" and _shape_dims(i["shape"])[0] == "f32"
-            and _shape_dims(i["shape"])[1] in leaf_shapes)
+            and i["opcode"] == "add" and shape_dims(i["shape"])[0] == "f32"
+            and shape_dims(i["shape"])[1] in leaf_shapes)
 
     assert balance_adds(inside) == leaf_shapes
     assert balance_adds(outside) == leaf_shapes
+
+
+def test_running_sum_update_reads_no_stale_mean(step_hlo):
+    """GraB keeps its running sum uncentered (``s = sum eps g`` beside
+    ``e = sum eps``), so the stale mean ``m_prev`` is read once a
+    microbatch, by the inner product, and not again by the running sum's
+    update: in the microbatch loop's body each ``m_prev`` leaf has one
+    reader, under ``grab_balance``, and nothing computed elementwise from it
+    leaves the body. (Centered, ``s + eps (g - m_prev)`` carries a value
+    computed from every leaf of ``m_prev`` out of the body.)"""
+    arm, ins, leaf_shapes, _ = step_hlo
+    if arm == "rr":
+        return
+    loop = microbatch_loop(ins)
+    assert len(loop.stale_mean) == sum(leaf_shapes.values())
+    for leaf in loop.stale_mean:
+        direct = loop.reads(leaf["name"])
+        assert [r["scope"] for r in direct] == ["grab_balance"], \
+            (leaf["name"], [(r["name"], r["op_name"]) for r in direct])
+        # what is computed elementwise from the leaf (as large as it, or a
+        # tuple holding such): none of it is a value the loop carries
+        size = int(np.prod(shape_dims(leaf["shape"])[1]))
+        todo, seen = list(direct), set()
+        while todo:
+            i = todo.pop()
+            if i["name"] in seen:
+                continue
+            seen.add(i["name"])
+            assert i["name"] not in loop.root["operands"], \
+                (leaf["name"], i["name"], i["op_name"])
+            todo += [r for r in loop.reads(i["name"])
+                     if r["shape"].startswith("(")
+                     or np.prod(shape_dims(r["shape"])[1]) >= size]
 
 
 def test_adamw_moments_fall_under_optimizer(step_hlo):
@@ -186,7 +163,7 @@ def test_adamw_moments_fall_under_optimizer(step_hlo):
     _, ins, leaf_shapes, _ = step_hlo
     opt = [i for i in ins if i["scope"] == "optimizer"]
     assert any(i["opcode"] == "sqrt" for i in opt)
-    muls = [_shape_dims(i["shape"]) for i in opt if i["opcode"] == "multiply"]
+    muls = [shape_dims(i["shape"]) for i in opt if i["opcode"] == "multiply"]
     for shape in leaf_shapes:            # b1 * m, b2 * v and more per leaf
         assert muls.count(("f32", shape)) >= 2, (shape, muls)
     # nothing of the optimizer's squares runs under another scope
@@ -197,7 +174,7 @@ def test_adamw_moments_fall_under_optimizer(step_hlo):
 def test_accumulation_falls_under_grad_accum(step_hlo):
     """The f32 accumulator's adds are under ``grad_accum``."""
     _, ins, leaf_shapes, _ = step_hlo
-    adds = [_shape_dims(i["shape"]) for i in ins
+    adds = [shape_dims(i["shape"]) for i in ins
             if i["scope"] == "grad_accum" and i["opcode"] == "add"]
     for shape in leaf_shapes:
         assert ("f32", shape) in adds, (shape, adds)
@@ -214,7 +191,8 @@ def test_every_large_instruction_is_scoped(step_hlo):
            and i["op_name"] is not None]
     assert run
     big_unscoped = [(i["name"], i["shape"][:40], i["op_name"]) for i in run
-                    if i["scope"] is None and _bytes(i["shape"]) >= smallest]
+                    if i["scope"] is None
+                    and hlo_bytes(i["shape"]) >= smallest]
     assert big_unscoped == []
 
 
@@ -230,6 +208,6 @@ def test_rollover_falls_under_grab_rollover():
     hlo = jax.jit(grab_rollover, static_argnames="grab_cfg").lower(
         grab, grab_cfg=cfg).compile().as_text()
     assert hlo.startswith("HloModule jit_grab_rollover")
-    named = [i for i in _instructions(hlo) if i["op_name"] is not None
+    named = [i for i in instructions(hlo) if i["op_name"] is not None
              and i["opcode"] not in PLUMBING]
     assert {i["scope"] for i in named} <= {"grab_rollover"}

@@ -25,7 +25,7 @@ from repro.data.synthetic import synthetic_classification
 from repro.models.paper_models import logreg_init, logreg_loss
 from repro.optim import constant, sgdm
 from repro.train import LoopConfig, run_training
-from repro.train.checkpoint import list_checkpoints
+from repro.train.checkpoint import CheckpointManager, list_checkpoints
 
 
 class ClsDataset:
@@ -72,6 +72,11 @@ def test_kill_resume_is_bit_identical(ordering, workers):
         for s, path in list_checkpoints(db):
             if s > kill_step:
                 shutil.rmtree(path)
+        # the mid-epoch checkpoint holds the GraB state's sum of signs
+        # ``e``: the epoch's signs so far in full-pytree mode, 0 in cd-grab
+        kill = CheckpointManager(db).restore(state_a)[0]
+        done = np.asarray(kill.signs, np.int64)[:int(kill.grab.t)]
+        assert float(kill.grab.e) == (done.sum() if workers == 1 else 0)
         state_b, hist_b = _run(ordering, workers, ckpt_dir=db, ckpt_every=1)
 
         # resumed from the exact step: only the remaining steps re-ran

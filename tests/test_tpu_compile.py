@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiles for a TPU v5e chip at real widths.
+"""Every Pallas kernel compiles for a TPU v5e chip at real widths, and the
+GraB train step compiles to the fusions its HBM bytes assume.
 
 Nothing runs: each case lowers the public ``kernels.ops`` wrapper with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology and
@@ -14,6 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from _hlo import instructions, microbatch_loop
 
 
 @pytest.fixture(scope="module")
@@ -68,3 +70,39 @@ def test_chunked_case_takes_the_chunked_kernel():
     one: at k=131072 the plain kernel's full-k tiles exceed the budget."""
     impl, chunk_k = ops.select_coord_impl(8, 131072)
     assert impl == "chunked" and chunk_k % 1024 == 0, (impl, chunk_k)
+
+
+def test_grab_step_reads_the_stale_mean_once_a_microbatch(one_chip):
+    """In the full-pytree GraB step compiled for the chip, each leaf of the
+    stale mean ``m_prev`` is read by one fusion a microbatch, the inner
+    product's; the running sum's update, fused with the gradient
+    accumulator's, reads no ``m_prev`` (it adds ``eps * g`` to the
+    uncentered sum). A centered update would make that fusion a second
+    reader of every leaf: 4 more bytes per parameter a microbatch."""
+    from repro.configs import get_config
+    from repro.core.grab import GrabConfig
+    from repro.models import lm
+    from repro.optim import adamw, constant
+    from repro.train.step import build_train_step, init_train_state
+
+    model = get_config("phi3-mini-3.8b")[1].with_(param_dtype="bfloat16")
+    cfg, opt = GrabConfig(), adamw()
+    step = jax.jit(build_train_step(
+        lambda p, mb: lm.loss_fn(p, model, mb, remat=True), opt,
+        constant(1e-3), cfg, n_micro_per_epoch=8), donate_argnums=(0,))
+    state = jax.eval_shape(lambda: init_train_state(
+        lm.init_lm(jax.random.PRNGKey(0), model), opt, cfg,
+        n_micro_per_epoch=8))
+    place = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    batch = {k: jax.ShapeDtypeStruct((4, 2, 16), jnp.int32,
+                                     sharding=one_chip)
+             for k in ("tokens", "labels")}
+    loop = microbatch_loop(instructions(
+        step.lower(place(state), batch).compile().as_text()))
+    assert len(loop.stale_mean) == len(jax.tree.leaves(state.grab.m_prev))
+    for leaf in loop.stale_mean:
+        reads = loop.reads(leaf["name"])
+        assert [r["opcode"] for r in reads] == ["fusion"], \
+            (leaf["name"], [(r["name"], r["op_name"]) for r in reads])

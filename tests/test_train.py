@@ -7,12 +7,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.grab import GrabConfig, grab_epoch_end, grab_step
+from repro.core.balance import alweiss_sign
+from repro.core.grab import (GrabConfig, grab_epoch_end, grab_running_sum,
+                             grab_step)
 from repro.models.paper_models import logreg_init, logreg_loss
 from repro.obs import MetricsRegistry
 from repro.optim import adamw, constant, sgdm
 from repro.train import (CheckpointManager, LoopConfig, build_train_step,
                          init_train_state, run_training)
+from repro.train.loop import grab_rollover
 from repro.data.synthetic import synthetic_classification
 
 
@@ -109,6 +112,70 @@ def test_pair_balance_step_keeps_its_stash_in_m_acc():
                for x in jax.tree.leaves(got.m_acc))
 
 
+@pytest.mark.parametrize("mean", [1.0, 10.0])
+@pytest.mark.parametrize("balancer", ["deterministic", "alweiss"])
+def test_uncentered_sum_balances_like_the_centered_one(balancer, mean):
+    """Over two epochs of the built step and its rollover, the uncentered
+    running sum (``s = sum eps g``, ``e = sum eps``) picks the signs that
+    the centered recurrence ``s += eps (g - m_prev)`` picks, in epoch 1
+    against a nonzero stale mean too: ``grab_running_sum`` equals the
+    centered sum within rtol 1e-5, ``e`` is the sum of the epoch's signs,
+    and ``m_prev`` after each rollover is the epoch's mean gradient. The
+    loss's gradient is the microbatch's row whatever the parameters, a mean
+    of ``mean`` plus unit noise per coordinate, so the reference knows each
+    gradient."""
+    n_micro, steps, epochs = 8, 3, 2
+    n = n_micro * steps
+    rng = np.random.default_rng(7)
+    rows = {"w": mean * rng.choice([-1.0, 1.0], (5, 4))
+            + rng.normal(size=(n, 5, 4)),
+            "b": mean + rng.normal(size=(n, 4))}
+    rows = jax.tree.map(lambda x: x.astype(np.float32), rows)
+    params = {"w": jnp.zeros((5, 4), jnp.float32),
+              "b": jnp.zeros((4,), jnp.float32)}
+    loss_fn = lambda p, mb: (sum(jnp.sum(p[k] * mb[k].mean(0)) for k in p),
+                             {})
+    cfg = GrabConfig(balancer=balancer, alweiss_c=30.0, seed=3)
+    step = jax.jit(build_train_step(loss_fn, sgdm(0.9), constant(0.05), cfg,
+                                    n_micro_per_epoch=n))
+    rollover = jax.jit(grab_rollover, static_argnames="grab_cfg")
+    state = init_train_state(params, sgdm(0.9), cfg)
+
+    flat = lambda t: np.concatenate([np.ravel(x) for x in jax.tree.leaves(t)])
+    vecs = np.stack([flat(jax.tree.map(lambda x: x[i], rows))
+                     for i in range(n)])
+    ref_m, ref_key = np.zeros(vecs.shape[1], np.float32), jax.random.PRNGKey(3)
+    for epoch in range(epochs):
+        order = rng.permutation(n)
+        ref_s, signs, ref_signs = np.zeros_like(ref_m), [], []
+        for k in range(steps):
+            idx = order[k * n_micro:(k + 1) * n_micro]
+            batch = jax.tree.map(lambda x: x[idx][:, None], rows)
+            state, metrics = step(state, batch)
+            signs += np.asarray(metrics["signs"]).tolist()
+            for z in vecs[idx] - ref_m:          # the centered recurrence
+                dot = np.float32(np.dot(ref_s, z))
+                if balancer == "deterministic":
+                    eps = 1 if dot <= 0 else -1
+                else:
+                    ref_key, sub = jax.random.split(ref_key)
+                    eps = int(alweiss_sign(jnp.float32(dot), jnp.float32(30.0),
+                                           sub))
+                ref_s = ref_s + np.float32(eps) * z
+                ref_signs.append(eps)
+            assert signs == ref_signs, (epoch, k)
+            got = flat(grab_running_sum(state.grab, cfg))
+            assert np.linalg.norm(got - ref_s) <= 1e-5 * np.linalg.norm(ref_s)
+            np.testing.assert_allclose(got, ref_s, rtol=1e-5,
+                                       atol=1e-5 * np.abs(ref_s).max())
+            assert float(state.grab.e) == sum(signs)
+        state = state._replace(grab=rollover(state.grab, grab_cfg=cfg))
+        ref_m = vecs.mean(0).astype(np.float32)
+        np.testing.assert_allclose(flat(state.grab.m_prev), ref_m, rtol=1e-5)
+        assert float(state.grab.e) == 0.0
+        assert not flat(state.grab.s).any()
+
+
 def test_grab_state_none_for_rr():
     ds, params, loss_fn = _setup()
     step = jax.jit(build_train_step(loss_fn, sgdm(0.9), constant(0.05),
@@ -196,21 +263,28 @@ def test_loop_records_compiled_step_bytes():
 @pytest.mark.parametrize("ordering", ["grab", "rr"])
 def test_loop_records_grab_state_bytes(ordering):
     """The loop records the GraB state's bytes on one chip as the
-    ``grab.state_bytes`` gauge, once, from shapes alone; rr has no GraB
-    state and no gauge."""
+    ``grab.state_bytes`` gauge, once, from shapes alone, and the epoch's
+    sign imbalance (|sum of its signs| over their number, from the sign
+    buffer it fetches anyway) as ``grab.sign_imbalance``, once an epoch; rr
+    has no GraB state and neither gauge."""
     ds, params, loss_fn = _setup()
     reg = MetricsRegistry(print_events=False)
-    cfg = LoopConfig(epochs=1, n_micro=8, ordering=ordering, log_every=0,
+    cfg = LoopConfig(epochs=2, n_micro=8, ordering=ordering, log_every=0,
                      metrics=reg)
     state, _ = run_training(loss_fn, params, sgdm(0.9), constant(0.05), ds,
                             4, cfg)
     gauges = reg.summary()["gauges"]
     if ordering == "rr":
         assert "grab.state_bytes" not in gauges
+        assert "grab.sign_imbalance" not in gauges
         return
     assert gauges["grab.state_bytes"]["n"] == 1
     assert gauges["grab.state_bytes"]["last"] == sum(
         x.nbytes for x in jax.tree.leaves(state.grab))
+    last_epoch = np.asarray(state.signs, np.int64)     # epoch 1's signs
+    assert gauges["grab.sign_imbalance"]["n"] == 2
+    assert gauges["grab.sign_imbalance"]["last"] == \
+        abs(last_epoch.sum()) / last_epoch.size
 
 
 def test_checkpoint_roundtrip_and_resume():
