@@ -32,10 +32,9 @@ Two measurements, both CPU-friendly:
    per-epoch win of ISSUE 5's dispatch-asynchronous refactor.
 
 5. **Compressed sign wire** (``--sign-wire``): herding bound with the exact
-   f32 sign wire vs the quantized int8 wire (sketch-mode dataflow), the
-   relative ordering-quality drift per epoch, and the analytic wire
-   bytes/device for each format — the quality-vs-bandwidth trade of
-   ISSUE 6's int8 packed exchange.
+   f32 sign wire vs the quantized int8 wire (sketch-mode dataflow) and the
+   relative ordering-quality drift per epoch — the quality price of the
+   int8 packed exchange.
 
 CSV rows: kind,W,epoch,value. Every run also emits ``BENCH_cd_grab.json``
 (``--json`` to relocate) with the same rows plus run metadata, so the perf
@@ -116,22 +115,17 @@ def run_herding(n: int, d: int, epochs: int, workers: tuple, seed: int):
 def run_sign_wire(n: int, d: int, epochs: int, workers: tuple, seed: int,
                   k: int):
     """Compressed-wire axis (``--sign-wire``): what the int8 sign wire costs
-    in ordering quality and what it saves on the wire, per W.
+    in ordering quality, per W.
 
-    Quality: the herding harness runs twice through the *sketch-mode* sign
-    dataflow (the path the wire format lives on) — once exact
-    (``sign_wire="f32"``), once quantized (``"int8"``) — and reports both
-    bounds plus their relative drift per epoch. The drift is the entire
-    quality price of the compression: signs are still exact ±1, only the
-    sketched pair-difference rows the scan dots against are rounded.
-
-    Wire: analytic bytes/device/epoch for each format from
-    ``sign_collective_terms`` (W workers on W devices, one exchange per odd
-    step for f32, one deferred packed gather for int8) and their ratio —
-    4k / (k + 4) per row, ≥ 3.5 for k ≥ 56.
+    The herding harness runs twice through the *sketch-mode* sign dataflow
+    (the path the wire format lives on) — once exact (``sign_wire="f32"``),
+    once quantized (``"int8"``) — and reports both bounds plus their
+    relative drift per epoch. The drift is the entire quality price of the
+    compression: signs are still exact ±1, only the sketched
+    pair-difference rows the scan dots against are rounded. (What the wire
+    saves, 4k / (k + 4) bytes per row, is pinned on the compiled mesh step
+    by ``tests/test_mesh_cd_grab.py``.)
     """
-    from repro.launch.roofline import sign_collective_terms
-
     rng = np.random.default_rng(seed)
     zs = rng.normal(size=(n, d)).astype(np.float32)
     rows = []
@@ -144,15 +138,6 @@ def run_sign_wire(n: int, d: int, epochs: int, workers: tuple, seed: int,
             rows += [("herding_f32", w, epoch, bf),
                      ("herding_int8", w, epoch, b8),
                      ("herding_wire_drift", w, epoch, (b8 - bf) / bf)]
-        if w > 1:
-            pair_steps = (n // w) // 2
-            tf = sign_collective_terms(w, k, pair_steps, group=w, wire="f32")
-            t8 = sign_collective_terms(w, k, pair_steps, group=w, wire="int8")
-            bpd_f, bpd_8 = (tf["sign_collective_bytes_per_dev"],
-                            t8["sign_collective_bytes_per_dev"])
-            rows += [("sign_bytes_per_dev_f32", w, 0, bpd_f),
-                     ("sign_bytes_per_dev_int8", w, 0, bpd_8),
-                     ("sign_bytes_ratio", w, 0, bpd_f / bpd_8)]
     return rows
 
 
@@ -214,8 +199,8 @@ def run_loop_wallclock(epochs: int, n: int = 512, d: int = 64,
     vs dispatch-asynchronous, on this process's real device mesh.
 
     Both runs take the identical launcher path (``LoopConfig.mesh``: jitted
-    step with explicit in_shardings, donated state, hillclimb-default
-    cd-grab constraints, W = device count workers); the only difference is
+    step with explicit in_shardings, donated state, the cd-grab slab
+    constraint, W = device count workers); the only difference is
     ``sync_transfers`` — the legacy loop blocks on a loss + sign fetch
     every step, the async loop keeps signs in the device-resident buffer
     and fetches once per epoch. Rows:
@@ -318,11 +303,10 @@ def main(argv=None):
                     help="also run the end-to-end loop sweep")
     ap.add_argument("--sign-wire", action="store_true",
                     help="also run the compressed-wire axis: herding bound "
-                         "f32 vs int8 sign wire (sketch mode) plus analytic "
-                         "bytes/device per format (see run_sign_wire)")
+                         "f32 vs int8 sign wire (sketch mode; see "
+                         "run_sign_wire)")
     ap.add_argument("--wire-k", type=int, default=32,
-                    help="sketch dim for --sign-wire (wire bytes ratio is "
-                         "4k/(k+4))")
+                    help="sketch dim for --sign-wire")
     ap.add_argument("--wallclock", action="store_true",
                     help="also time the sign dataflow vs the device step")
     ap.add_argument("--wallclock-d", type=int, default=65_536,

@@ -320,7 +320,7 @@ def cd_grab_run(jax, cfg, seq_len, seed, mesh, *, keep_moment=True):
 
     loop_cfg = LoopConfig(epochs=FOUR_EPOCHS, n_micro=FOUR_N_MICRO,
                           ordering="cd-grab", workers=FOUR_W,
-                          sign_wire="int8", mesh=mesh, log_every=1, seed=seed)
+                          mesh=mesh, log_every=1, seed=seed)
     state, hist, summary = _run(loss_fn, _host_params(jax, cfg, seed), ds,
                                 loop_cfg, grab_cfg, keep_signs)
     run = {"losses": np.array([h["loss"] for h in hist]), "signs": signs,
@@ -484,8 +484,6 @@ def main(argv=None) -> int:
     print(f"device_count: {jax.device_count()}")
     print(f"jax: {jax.__version__}")
     print(f"compile cache: {cache_dir}")
-    check("repro.launch.dryrun" not in sys.modules,
-          "the dry-run module (forced CPU devices) is not imported")
 
     t0 = time.perf_counter()
     if args.four_chip:

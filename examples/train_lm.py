@@ -50,8 +50,8 @@ def main():
                          "mesh over all local devices (force several CPU "
                          "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N), "
-                         "explicit in_shardings + the hillclimb-winning "
-                         "cd-grab constraint set, donated device state")
+                         "explicit in_shardings + the cd-grab batch-slab "
+                         "constraint, donated device state")
     ap.add_argument("--sketch-dim", type=int, default=0,
                     help="GraB sketch width k (0 = full-pytree balance; "
                          "cd-grab on a mesh uses k for the sign all-gather)")
@@ -138,7 +138,6 @@ def main():
     total = (args.epochs or p["epochs"]) * steps_per_epoch
     loop = LoopConfig(epochs=args.epochs or p["epochs"], n_micro=p["n_micro"],
                       ordering=args.ordering, workers=args.workers,
-                      sign_wire=args.sign_wire, sign_hier=args.sign_hier,
                       ckpt_dir=args.ckpt_dir, log_every=10, mesh=mesh,
                       loader_workers=args.loader_workers,
                       loader_window=args.loader_window,

@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import importlib
 
-from repro.models.config import ModelConfig, SHAPES, SHAPES_BY_NAME, ShapeConfig
+from repro.models.config import ModelConfig
 
 _MODULES = {
     "qwen2-7b": "qwen2_7b",
@@ -31,9 +31,3 @@ def get_config(arch: str) -> tuple[ModelConfig, ModelConfig]:
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     return mod.FULL, mod.SMOKE
 
-
-def cell_applicable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Is (arch x shape) a runnable cell? Returns (ok, reason-if-skipped)."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "SKIP(full-attn)"
-    return True, ""

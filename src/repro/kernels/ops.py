@@ -195,9 +195,9 @@ def gla(q, k, v, w, u=None, return_state: bool = False,
     """Implementation dispatcher used by the model blocks.
 
     * ``pallas`` — the VMEM-resident kernel (default on real TPU).
-    * ``xla``    — pure-jnp ``lax.scan`` (default off-TPU and for the
-      multi-device dry-run: a pallas_call inside a pjit would be opaque to
-      the SPMD partitioner, so sharded lowering paths use plain XLA).
+    * ``xla``    — pure-jnp ``lax.scan`` (default off-TPU; a pallas_call
+      inside a pjit would be opaque to the SPMD partitioner, so sharded
+      lowering paths use plain XLA).
 
     Override with REPRO_GLA_IMPL=pallas|xla. ``return_state`` (prefill
     cache priming) always takes the XLA path.

@@ -1,14 +1,9 @@
-"""Launcher glue for the *live* training loop (``train.loop.run_training``
-with ``LoopConfig.mesh``).
-
-PRs 2/4 built the mesh-native CD-GraB machinery for the dry-run launcher:
-``cd_grab_state_specs`` in_shardings, ``constrain_grads`` from the param
-specs, the ``micro_workers`` constraint hillclimb. This module folds exactly
-that configuration into the default launch path — same spec functions, same
-``make_cd_constraints`` resolver as ``launch.specs.make_cell``, so what the
-dry-run measured is what training runs. The live loop defaults the
-constraint set to the hillclimb winner (``CD_GRAB_DEFAULT_CONSTRAINT``)
-instead of sweeping.
+"""The mesh step of the training loop (``train.loop.run_training`` with
+``LoopConfig.mesh``): the train step jitted with in_shardings from
+``launch.sharding`` (``cd_grab_state_specs`` for CD-GraB's worker-stacked
+stash, ``state_specs`` otherwise), ``constrain_grads`` from the param specs,
+CD-GraB's batch-slab constraint (``make_cd_constraints``), and a donated
+state placed onto the mesh.
 """
 from __future__ import annotations
 
@@ -30,7 +25,6 @@ def build_live_step(loss_fn: Callable, optimizer, lr_schedule,
                     batch_template, n_micro: int, n_micro_total: int,
                     n_workers: int = 1, sketch: Optional[Sketch] = None,
                     shard_policy: Optional[ShardPolicy] = None,
-                    cd_constraints: Optional[str] = None,
                     data_axis: str = "data"):
     """Build the mesh-aware, donation-enabled jitted train step and the
     sharded initial :class:`TrainState` for the live loop.
@@ -49,8 +43,6 @@ def build_live_step(loss_fn: Callable, optimizer, lr_schedule,
 
     ``batch_template``: a host pytree with the per-step batch structure
     (leaves ``[n_micro, micro, ...]``) — only shapes/structure are read.
-    ``cd_constraints`` names a ``CD_GRAB_CANDIDATES`` entry; None applies
-    the hillclimb-winning default.
     """
     policy = shard_policy or ShardPolicy()
     cd_grab = n_workers > 1
@@ -65,7 +57,7 @@ def build_live_step(loss_fn: Callable, optimizer, lr_schedule,
         assert grab_cfg is not None and grab_cfg.pair_balance
         assert n_workers % mesh.shape[data_axis] == 0, \
             (n_workers, dict(mesh.shape))
-        cd_cons = make_cd_constraints(cd_constraints, params, batch_template,
+        cd_cons = make_cd_constraints(None, params, batch_template,
                                       policy, mesh, data_axis=data_axis)
 
     step_fn = build_train_step(

@@ -1,7 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant — importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before first jax init).
+Functions, not module-level constants: importing this module never touches
+jax device state.
 
 Every mesh here has ``Auto`` axes: the step pins its intermediates with
 ``with_sharding_constraint`` (``launch.sharding``), which refuses the
@@ -11,18 +11,6 @@ from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 (one v5e pod, 256 chips) or 2x16x16 (two pods, 512 chips).
-
-    Axes: 'data' carries DP + FSDP; 'model' carries TP (+ MoE ff sharding);
-    'pod' is pure DP across the slower inter-pod links (its gradient
-    all-reduce is the natural place for int8 compression).
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(model_parallel: int = 16):

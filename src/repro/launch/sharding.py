@@ -1,7 +1,6 @@
 """Rule-based sharding: tree path -> PartitionSpec.
 
-Policy (MaxText-flavored 2D FSDP x TP, per-arch overridable — this is the
-main §Perf hillclimb lever):
+Policy (MaxText-flavored 2D FSDP x TP, per-arch overridable):
 
 * big 2D projections: input dim on the FSDP axis ('data'), output dim on
   'model' (up-projections) — transposed for down-projections so the matmul's
@@ -151,33 +150,13 @@ def _stack_worker_spec(spec: P, data_axis: str) -> P:
     return P(data_axis, *(None if ax == data_axis else ax for ax in spec))
 
 
-# Candidate constraint sets for the cd-grab sharding hillclimb, weakest
-# first: which of the three [W, ...]-leading intermediates inside
-# ``micro_workers`` get an explicit with_sharding_constraint. "none" leaves
-# XLA's propagation alone (the seed behavior — its stash-vs-gradient
-# resharding choice shows up as unattributed all-gather bytes); the dry-run
-# compiles every candidate and keeps the one with the fewest measured HLO
-# collective bytes (see ``launch.dryrun.run_cell``).
-CD_GRAB_CANDIDATES = ("none", "slab", "slab_grads", "full")
-
-# The measured hillclimb winner (EXPERIMENTS.md §micro_workers sharding
-# hillclimb): the explicit slab constraint removes the stash-resharding
-# all-gathers XLA's propagation otherwise inserts (~106 KB/dev on the smoke
-# cells), and the stronger sets are no-ops on top of it. This is what the
-# *live* training loop applies by default when given a mesh
-# (``train.loop.LoopConfig.mesh`` -> ``launch.live``); the dry-run keeps
-# sweeping all of ``CD_GRAB_CANDIDATES`` and flags drift when the measured
-# best stops matching this default.
-CD_GRAB_DEFAULT_CONSTRAINT = "slab"
-
-
 def make_grad_pinner(params_tree, policy: ShardPolicy, mesh):
     """tree->tree callable applying the *gradient* PartitionSpecs (FSDP
     forced on, matching the grad/opt access pattern — see ``state_specs``)
-    to gradient-shaped pytrees via with_sharding_constraint. The single
-    ``constrain_grads`` every launch path (dry-run cells and the live loop)
-    passes to ``build_train_step``. Uses NamedShardings so it works without
-    an ambient ``with mesh:`` context."""
+    to gradient-shaped pytrees via with_sharding_constraint: the
+    ``constrain_grads`` the live loop (``launch.live``) passes to
+    ``build_train_step``. Uses NamedShardings so it works without an
+    ambient ``with mesh:`` context."""
     g_policy = dataclasses.replace(policy, fsdp=policy.fsdp or policy.zero1)
     g_shardings = named(mesh, tree_specs(params_tree, g_policy))
 
@@ -190,33 +169,22 @@ def make_grad_pinner(params_tree, policy: ShardPolicy, mesh):
 def make_cd_constraints(candidate: Optional[str], params_tree, batch_tree,
                         policy: ShardPolicy, mesh, *,
                         data_axis: str = "data"):
-    """Resolve a ``CD_GRAB_CANDIDATES`` name into the explicit
-    ``CdGrabConstraints`` applied inside ``micro_workers`` — the single
-    source of truth shared by the dry-run hillclimb (``launch.dryrun`` via
-    ``launch.specs.make_cell``) and the live loop (``launch.live``), so the
-    constraint set the sweep measured is exactly the one training runs.
+    """The explicit ``CdGrabConstraints`` applied inside ``micro_workers``:
+    the per-timestep [W, micro, ...] batch slab is pinned with its worker
+    rows over the data axis (:func:`cd_grab_slab_specs`). Left to XLA's
+    propagation, the step moves more collective bytes
+    (``tests/test_mesh_cd_grab.py`` compares the two compiled steps).
 
-    ``candidate=None`` resolves to ``CD_GRAB_DEFAULT_CONSTRAINT``.
-    ``batch_tree`` is the per-step batch pytree ([n_micro, micro, ...]
-    leaves — only its *structure* matters for the slab specs)."""
+    ``candidate`` must be None: it is a positional argument that older
+    callers still pass. ``batch_tree`` is the per-step batch pytree
+    ([n_micro, micro, ...] leaves; only its *structure* matters).
+    ``params_tree`` and ``policy`` are unused."""
     from repro.train.step import CdGrabConstraints
 
-    cand = candidate or CD_GRAB_DEFAULT_CONSTRAINT
-    assert cand in CD_GRAB_CANDIDATES, \
-        f"cd_constraints={cand!r}; known: {CD_GRAB_CANDIDATES}"
-
-    def pinner(spec_tree):
-        sh = named(mesh, spec_tree)
-        return lambda tree: jax.tree.map(
-            jax.lax.with_sharding_constraint, tree, sh)
-
-    stacked = cd_grab_stacked_grad_specs(params_tree, policy,
-                                         data_axis=data_axis)
-    return CdGrabConstraints(
-        slab=(pinner(cd_grab_slab_specs(batch_tree, data_axis=data_axis))
-              if cand != "none" else None),
-        grads=(pinner(stacked) if cand in ("slab_grads", "full") else None),
-        stash=pinner(stacked) if cand == "full" else None)
+    assert candidate is None, candidate
+    sh = named(mesh, cd_grab_slab_specs(batch_tree, data_axis=data_axis))
+    return CdGrabConstraints(slab=lambda tree: jax.tree.map(
+        jax.lax.with_sharding_constraint, tree, sh))
 
 
 def cd_grab_slab_specs(batch_tree, *, data_axis: str = "data"):
@@ -226,22 +194,6 @@ def cd_grab_slab_specs(batch_tree, *, data_axis: str = "data"):
     return jax.tree.map(lambda _: P(data_axis), batch_tree)
 
 
-def cd_grab_stacked_grad_specs(params_tree, policy: ShardPolicy, *,
-                               data_axis: str = "data"):
-    """Specs for worker-stacked gradient-shaped pytrees ([W, ...param] —
-    the vmapped per-worker grads and the pair stash): the per-worker layout
-    follows the gradient rules (FSDP forced on, as in the launcher's
-    ``constrain_grads``), then the worker axis is prepended via
-    :func:`_stack_worker_spec`. This is the same rule
-    :func:`cd_grab_state_specs` applies to the stash carried in the
-    TrainState, so the in_shardings and the in-scan constraints can never
-    disagree."""
-    g_policy = dataclasses.replace(policy, fsdp=policy.fsdp or policy.zero1)
-    base = tree_specs(params_tree, g_policy)
-    return jax.tree.map(lambda s: _stack_worker_spec(s, data_axis), base,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
 def cd_grab_state_specs(state, policy: ShardPolicy, *,
                         data_axis: str = "data"):
     """Specs for a TrainState carrying CD-GraB's W-worker GraB state.
@@ -249,8 +201,8 @@ def cd_grab_state_specs(state, policy: ShardPolicy, *,
     The pair stash (``grab/m_prev``, ``grab/m_acc``) has a leading worker
     axis: row w is worker w's stashed gradient, so it shards over the data
     axis — each DP shard keeps only its own workers' stash, and the only
-    cross-shard ordering traffic is the W-sign all-gather in
-    ``core.distributed.mesh_pair_signs`` (W·k floats per pair step).
+    cross-shard ordering traffic is the all-gather of the sketched sign
+    rows (``core.distributed``).
     The shared running sum and everything else follow :func:`state_specs`.
     """
     def is_stash(path):
@@ -262,7 +214,7 @@ def cd_grab_state_specs(state, policy: ShardPolicy, *,
     # put on the inner dims (a mesh axis may appear only once per spec, and
     # the worker axis is the stash's data-parallel dimension). Shape-level
     # unstacking (not leaf[0]) so abstract ShapeDtypeStruct states from
-    # eval_shape — the dry-run launcher's input — work too.
+    # eval_shape work too.
     slim = jax.tree_util.tree_map_with_path(
         lambda path, leaf: (jax.ShapeDtypeStruct(leaf.shape[1:], leaf.dtype)
                             if is_stash(path) else leaf), state)
@@ -272,17 +224,6 @@ def cd_grab_state_specs(state, policy: ShardPolicy, *,
         lambda path, spec: (_stack_worker_spec(spec, data_axis)
                             if is_stash(path) else spec),
         base, is_leaf=lambda x: isinstance(x, P))
-
-
-def batch_specs(batch_shapes, mesh):
-    """Shard every leaf's batch dim over the data axes.
-
-    Train batches are [n_micro, batch, ...] (batch dim = axis 1);
-    serve batches are [batch, ...] (axis 0). Heuristic: leaves with ndim >= 2
-    and a leading n_micro axis are tagged by the caller instead — here we
-    just take axis index from the caller-provided ``bdim``.
-    """
-    raise NotImplementedError("use explicit specs in dryrun/train drivers")
 
 
 def named(mesh, spec_tree):

@@ -1,2 +1,2 @@
-from repro.models.config import ModelConfig, ShapeConfig, SHAPES, SHAPES_BY_NAME
+from repro.models.config import ModelConfig
 from repro.models import lm, whisper, paper_models

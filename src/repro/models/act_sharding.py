@@ -2,15 +2,16 @@
 
 Left to itself, XLA's sharding propagation sometimes picks batch-replicated /
 d-model-sharded layouts for the layer-scan carry (observed on the 256-chip
-dry-run: 4.6 GiB replicated logits and 10 TB of spurious all-reduces). These
-hooks pin the canonical layout:
+dry-run, since deleted: 4.6 GiB replicated logits and 10 TB of spurious
+all-reduces). These hooks pin the canonical layout:
 
     activations [batch, seq, d]  -> P(data_axes, None, None)
     logits      [batch, seq, V]  -> P(data_axes, None, 'model')
 
 The hooks are global + optional: model code calls :func:`constrain` which is
-a no-op unless a launcher (dryrun / train driver) installed specs for the
-current mesh. Tests and single-device runs are untouched.
+a no-op unless :func:`set_activation_specs` installed specs for the current
+mesh. No training path installs them today (``tests/test_sharding.py``
+does); single-device runs are untouched.
 """
 from __future__ import annotations
 

@@ -7,9 +7,8 @@ for the 4k-train and 32k-prefill cells (a naive [B,H,T,S] tensor at 32k is
 in the backward, flash-attention-style).
 
 Note on causal overcompute: all KV chunks are visited for every Q chunk and
-masked — ~2x the useful attention FLOPs for causal inputs. This shows up in
-the roofline's MODEL_FLOPS / HLO_FLOPs ratio and is a recorded §Perf
-iteration (block-triangular chunk enumeration).
+masked — ~2x the useful attention FLOPs for causal inputs (block-triangular
+chunk enumeration would remove it).
 """
 from __future__ import annotations
 

@@ -7,7 +7,7 @@ right blocks from these fields.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,40 +81,5 @@ class ModelConfig:
     def attn_free(self) -> bool:
         return self.block == "rwkv6"
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch decode at 500k context without a full KV cache?"""
-        return self.block in ("rwkv6", "hymba")
-
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    """One input-shape cell from the assignment."""
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                 # 'train' | 'prefill' | 'decode'
-
-    @property
-    def is_train(self) -> bool:
-        return self.kind == "train"
-
-
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4_096, 256, "train"),
-    ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    ShapeConfig("long_500k", 524_288, 1, "decode"),
-)
-
-# Test/CI-scale cells: addressable by name (the mesh tests and CI compile a
-# real cd-grab dry-run cell on forced multi-device CPU meshes) but kept out
-# of the SHAPES sweep that --all iterates.
-SMOKE_SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_smoke", 128, 32, "train"),
-)
-
-SHAPES_BY_NAME = {s.name: s for s in SHAPES + SMOKE_SHAPES}

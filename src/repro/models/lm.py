@@ -4,7 +4,7 @@ One parameter pytree, one forward, four block families (dense / moe / rwkv6 /
 hymba). Layers are **stacked and scanned** (MaxText-style scan-over-layers):
 per-layer params carry a leading ``[L, ...]`` axis and the stack runs under a
 single ``lax.scan`` with optional per-layer remat — this keeps HLO size and
-compile time flat in depth, which matters for the 512-device dry-run.
+compile time flat in depth.
 
 Entry points:
   init_lm(key, cfg)                        -> params

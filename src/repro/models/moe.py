@@ -2,11 +2,11 @@
 
 Why this formulation (vs. megablocks / ragged_dot): every op here is an
 einsum or a cumsum, so XLA's SPMD partitioner shards it cleanly on the
-(data, model) mesh with no shard_map or data-dependent shapes — which is what
-the 512-device dry-run must prove. Expert weights are 3D ``[E, d, ff]``
-tensors 2D-sharded over ('data','model') like every other big weight.
+(data, model) mesh with no shard_map or data-dependent shapes. Expert
+weights are 3D ``[E, d, ff]`` tensors 2D-sharded over ('data','model') like
+every other big weight.
 
-Cost accounting (recorded in the roofline): dispatch+combine einsums add
+Cost accounting: dispatch+combine einsums add
 ``2 * E * C / (topk * 3 * ff)`` relative FLOPs — ~3% for mixtral's shapes at
 capacity 1.25 with 512-token groups. Tokens beyond expert capacity within a
 group are dropped (standard GShard semantics; capacity_factor configurable).

@@ -29,8 +29,7 @@ ENVELOPE = ("schema", "kind", "time_unix", "seq")
 
 # kind -> required payload fields (beyond the envelope).
 REQUIRED: Dict[str, tuple] = {
-    # one per run: static configuration + analytic sign-collective metadata
-    # (roofline terms next to which the measured step times land)
+    # one per run: the run's static configuration
     "run_meta": ("run", "config"),
     # a human-readable event (the loop's former prints, resume notices, ...)
     "event": ("msg",),

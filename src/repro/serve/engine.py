@@ -1,8 +1,7 @@
 """Serving: jit'd prefill + decode step builders and a small batched engine.
 
-The dry-run lowers exactly these two functions for the inference shape cells
-(``prefill_32k`` lowers prefill; ``decode_32k`` / ``long_500k`` lower one
-decode step against a seq_len-deep cache, per the assignment).
+``ServeEngine`` jits these two functions: prefill, then one decode step
+at a time against a max_len-deep cache.
 """
 from __future__ import annotations
 

@@ -58,18 +58,6 @@ def quantize_params(params, min_size: int = MIN_QUANT_SIZE):
     return jax.tree_util.tree_map_with_path(one, params)
 
 
-def quantize_abstract(params_abs, min_size: int = MIN_QUANT_SIZE):
-    """ShapeDtypeStruct version for the dry-run (no allocation)."""
-    def one(path, leaf):
-        if not _eligible(path, leaf, min_size):
-            return leaf
-        sshape = leaf.shape[-1:] if leaf.ndim <= 2 else \
-            (leaf.shape[0], leaf.shape[-1])
-        return {"q": jax.ShapeDtypeStruct(leaf.shape, jnp.int8),
-                "s": jax.ShapeDtypeStruct(sshape, jnp.float32)}
-    return jax.tree_util.tree_map_with_path(one, params_abs)
-
-
 def maybe_dequant(tree, dtype=jnp.bfloat16):
     """Restore plain arrays from any quantized leaves in ``tree``."""
     return jax.tree.map(

@@ -13,12 +13,11 @@ legacy host-synchronous behavior — one loss + sign fetch per step — kept
 only as the A/B baseline for ``benchmarks/cd_grab_scaling.py
 --wallclock-loop``.
 
-Passing ``LoopConfig.mesh`` runs the launcher path on real hardware: the
+Passing ``LoopConfig.mesh`` runs the mesh step (``launch.live``): the
 step is jitted with ``in_shardings`` from ``launch.sharding`` (the
 ``cd_grab_state_specs`` worker-stacked stash rules for cd-grab,
-``constrain_grads`` from the param specs) and the hillclimb-winning
-``CdGrabConstraints`` from the dry-run sweeps — one source of truth with
-``launch.dryrun`` (see ``launch.live``).
+``constrain_grads`` from the param specs) and CD-GraB's batch-slab
+constraint.
 
 Resume is **exact**: a checkpoint (mid-epoch or boundary) carries the sign
 buffer and GraB state inside ``TrainState``, so the loop continues from the
@@ -71,13 +70,6 @@ class LoopConfig:
     n_micro: int = 8              # microbatches per optimizer step
     ordering: str = "grab"        # grab | cd-grab | rr | so | flipflop
     workers: int = 1              # cd-grab only: W logical DP workers
-    sign_wire: str = "f32"        # cd-grab coordination wire: "f32" | "int8"
-    #                               (int8 packs the [W, k] rows to [W, k+4]
-    #                               int8 before the gather — ~4x fewer bytes,
-    #                               same signs on every shard; on the mesh
-    #                               path it also defers the exchange to one
-    #                               overlappable gather per step)
-    sign_hier: int = 0            # two-stage gather group size (0 = flat)
     ckpt_dir: Optional[str] = None
     ckpt_every_steps: int = 0     # 0 = once per epoch
     keep_ckpts: int = 3
@@ -95,11 +87,8 @@ class LoopConfig:
     #                               is disabled — the artifact IS the order)
     # --- launcher path (see launch.live) -----------------------------------
     mesh: Any = None              # jax Mesh: jit with explicit in_shardings,
-    #                               donate the state, apply the cd-grab
-    #                               constraint set below
+    #                               donate the state, pin the cd-grab slab
     shard_policy: Any = None      # launch.sharding.ShardPolicy (mesh only)
-    cd_constraints: Optional[str] = None  # CD_GRAB_CANDIDATES name; None =
-    #                               the measured hillclimb winner
     # --- data pipeline (repro.data.prefetch) -------------------------------
     loader_workers: int = 2       # window-prefetch assembly pool size
     loader_window: int = 4        # order_slice horizon, in optimizer steps
@@ -175,15 +164,6 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
     if cd_grab:
         if not grab_cfg.pair_balance:
             grab_cfg = dataclasses.replace(grab_cfg, pair_balance=True)
-        # loop-level sign-wire knobs override the GrabConfig defaults only
-        # when explicitly set, so callers passing a pre-configured grab_cfg
-        # keep their choice
-        if loop_cfg.sign_wire != "f32":
-            grab_cfg = dataclasses.replace(grab_cfg,
-                                           sign_wire=loop_cfg.sign_wire)
-        if loop_cfg.sign_hier:
-            grab_cfg = dataclasses.replace(grab_cfg,
-                                           sign_hier=loop_cfg.sign_hier)
         assert loop_cfg.n_micro % n_workers == 0, \
             (loop_cfg.n_micro, n_workers)
         assert (n_micro_total // n_workers) % 2 == 0, \
@@ -230,22 +210,7 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
                         pair_balance=grab_cfg.pair_balance,
                         sign_wire=grab_cfg.sign_wire,
                         sign_hier=grab_cfg.sign_hier)
-    meta_kw = {}
-    if cd_grab and n_workers > 1 and grab_cfg.sketch_dim > 0:
-        # analytic sign-collective roofline terms as run metadata, so the
-        # modeled wire bytes sit in the same record stream as the measured
-        # step times (group = W: one gathered row per logical worker —
-        # matches the live mesh path where W == the data-axis size)
-        from repro.launch.roofline import sign_collective_terms
-        deferred = (loop_cfg.mesh is not None
-                    and grab_cfg.sign_wire == "int8"
-                    and grab_cfg.balancer == "deterministic")
-        meta_kw["sign_collective"] = sign_collective_terms(
-            n_workers, grab_cfg.sketch_dim,
-            pair_steps=(n_micro_total // n_workers) // 2, group=n_workers,
-            wire=grab_cfg.sign_wire, hier_group=grab_cfg.sign_hier,
-            deferred=deferred)
-    reg.emit("run_meta", run="train.loop", config=run_meta, **meta_kw)
+    reg.emit("run_meta", run="train.loop", config=run_meta)
 
     # the shard-aware window-prefetching pipeline: whole [n_micro, ...]
     # step batches are order_slice'd, gathered, and stacked OFF this
@@ -260,9 +225,8 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
         sketch = make_sketch(params, grab_cfg.sketch_dim)
 
     if loop_cfg.mesh is not None:
-        # launcher path: explicit in_shardings + constraint set from
-        # launch.sharding (one source of truth with the dry-run), donated
-        # state, initial placement onto the mesh
+        # mesh step: explicit in_shardings + the slab constraint from
+        # launch.sharding, donated state, initial placement onto the mesh
         from repro.launch.live import build_live_step
         tmpl_micro = dataset.batch(np.arange(micro_size))
         batch_template = {k: np.stack([v] * loop_cfg.n_micro)
@@ -272,8 +236,7 @@ def run_training(loss_fn: Callable, params, optimizer, lr_schedule, dataset,
             params=params, batch_template=batch_template,
             n_micro=loop_cfg.n_micro, n_micro_total=n_micro_total,
             n_workers=n_workers, sketch=sketch,
-            shard_policy=loop_cfg.shard_policy,
-            cd_constraints=loop_cfg.cd_constraints)
+            shard_policy=loop_cfg.shard_policy)
     else:
         # donated, so old and new state are never both live across a step;
         # the params are copied in so that donation never deletes the

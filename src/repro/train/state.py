@@ -10,7 +10,7 @@ dispatch. It lives inside the state (not the metrics) so it is donated across
 steps (in-place update), checkpointed with everything else (a mid-epoch
 snapshot carries its partial signs), and resharded on restore like any other
 leaf. ``None`` for orderings that emit no signs (RR/SO/FlipFlop) and for
-abstract dry-run cells that never run an epoch.
+unit steps that never run an epoch.
 """
 from __future__ import annotations
 

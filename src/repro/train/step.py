@@ -59,18 +59,13 @@ from repro.utils.tree import tree_zeros_like
 
 
 class CdGrabConstraints(NamedTuple):
-    """Explicit sharding constraints for the [W, ...]-leading intermediates
-    inside ``micro_workers`` (the CD-GraB scan body). Each field is an
-    optional tree->tree callable (with_sharding_constraint under the hood);
-    None leaves that intermediate to XLA's propagation. The launcher builds
-    these from ``launch.sharding`` (``cd_grab_slab_specs`` /
-    ``cd_grab_stacked_grad_specs``) so the constraint set and the
-    ``cd_grab_state_specs`` in_shardings come from one source of truth, and
-    the dry-run hillclimbs over ``launch.sharding.CD_GRAB_CANDIDATES`` to
-    pick the measured-best set."""
-    slab: Optional[Callable] = None     # [W, micro, ...] per-timestep batch
-    grads: Optional[Callable] = None    # vmapped per-worker grads [W, ...]
-    stash: Optional[Callable] = None    # worker-stacked pair stash [W, ...]
+    """Explicit sharding constraints inside ``micro_workers`` (the CD-GraB
+    scan body). ``slab`` is an optional tree->tree callable
+    (with_sharding_constraint under the hood) for the per-timestep
+    [W, micro, ...] batch slab; None leaves it to XLA's propagation. The
+    launcher builds it with ``launch.sharding.make_cd_constraints``, from
+    the same rules as the ``cd_grab_state_specs`` in_shardings."""
+    slab: Optional[Callable] = None
 
 
 def build_train_step(loss_fn: Callable, optimizer: Optimizer,
@@ -116,18 +111,14 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
     ``constrain_grads``: optional tree->tree applying param PartitionSpecs
     (with_sharding_constraint) to gradient-shaped pytrees. Without it, XLA's
     propagation can keep the f32 grad accumulator and GraB state *unsharded*
-    through the microbatch scan — observed as 7 GiB-per-tensor temps on the
-    256-chip dry-run. The launcher always passes this under pjit. (The
-    worker-stacked stash of the CD-GraB path is pinned by the launcher via
-    ``launch.sharding.cd_grab_state_specs`` instead — its leading axis is
-    not gradient-shaped.)
+    through the microbatch scan. The launcher always passes this on a mesh.
+    (The worker-stacked stash of the CD-GraB path is pinned by the
+    launcher's ``launch.sharding.cd_grab_state_specs`` in_shardings instead
+    — its leading axis is not gradient-shaped.)
 
-    ``cd_constraints``: optional :class:`CdGrabConstraints` applying
-    explicit in-scan constraints to the CD-GraB intermediates (batch slab /
-    per-worker grads / stash). Without them XLA picks the stash-vs-gradient
-    resharding itself, which the dry-run observed as unattributed extra
-    all-gather bytes; the launcher hillclimbs over candidate sets and passes
-    the measured-best one.
+    ``cd_constraints``: optional :class:`CdGrabConstraints` pinning the
+    CD-GraB batch slab inside the scan; the launcher passes
+    ``launch.sharding.make_cd_constraints``.
     """
     pin = constrain_grads or (lambda t: t)
     cdc = cd_constraints or CdGrabConstraints()
@@ -155,9 +146,6 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             return gs
         s = gs.s if grab_cfg.sketch_dim > 0 else pin(gs.s)
         if n_workers > 1:          # stash carries a worker axis; see above
-            if cdc.stash is not None:
-                return gs._replace(s=s, m_prev=cdc.stash(gs.m_prev),
-                                   m_acc=cdc.stash(gs.m_acc))
             return gs._replace(s=s)
         m_acc = None if gs.m_acc is None else pin(gs.m_acc)
         return gs._replace(s=s, m_prev=pin(gs.m_prev), m_acc=m_acc)
@@ -201,8 +189,6 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                     mb_w = cdc.slab(mb_w)
                 (losses, metrics), grads = jax.vmap(
                     fwd_bwd, in_axes=(None, 0))(params, mb_w)
-                if cdc.grads is not None:
-                    grads = cdc.grads(grads)
             return losses, grads
 
         def accumulate_mean(acc, grads):
@@ -298,8 +284,8 @@ def init_train_state(params, optimizer: Optimizer,
                      n_micro_per_epoch: int = 0) -> TrainState:
     """``n_micro_per_epoch > 0`` (and a grab_cfg) allocates the
     device-resident ``[T, W]`` int8 sign buffer in ``state.signs`` — the live
-    loop's once-per-epoch sign fetch path. Dry-run cells and unit steps that
-    read ``metrics["signs"]`` directly leave it at 0 (``signs=None``)."""
+    loop's once-per-epoch sign fetch path. Unit steps that read
+    ``metrics["signs"]`` directly leave it at 0 (``signs=None``)."""
     if grab_cfg is None:
         grab = None
     elif n_workers > 1:

@@ -1,5 +1,6 @@
 """Reading a compiled program's HLO text, for the tests that check what the
-train step compiles to (``test_named_scopes.py`` on the CPU,
+train step compiles to (``test_named_scopes.py`` and, through
+``_mesh_worker.py``, ``test_mesh_cd_grab.py`` on the CPU,
 ``test_tpu_compile.py`` for a described v5e chip)."""
 import collections
 import re
@@ -42,9 +43,9 @@ def shape_dims(shape: str):
 def instructions(hlo: str) -> list:
     """Every instruction as a dict: its computation, whether that is a
     fusion's body or the entry, name, shape, opcode, ``op_name``, the
-    computations it calls, for a loop its condition and body, its operands,
-    whether it is its computation's root and, for a tuple's element, the
-    element's index."""
+    computations it calls, for a loop its condition, body and known trip
+    count, its operands, whether it is its computation's root and, for a
+    tuple's element, the element's index."""
     out, comp, entry = [], None, False
     for line in hlo.splitlines():
         m = _COMP.match(line)
@@ -56,6 +57,7 @@ def instructions(hlo: str) -> list:
             op = re.search(r'op_name="([^"]*)"', line)
             args = line[m.end():].split(")", 1)[0]
             index = re.search(r"\bindex=(\d+)", line)
+            trips = re.search(r'"known_trip_count":\{"n":"(\d+)"', line)
             out.append({"comp": comp, "entry": entry, "name": m.group(1),
                         "shape": m.group(2), "opcode": m.group(3),
                         "op_name": op.group(1) if op else None,
@@ -65,7 +67,8 @@ def instructions(hlo: str) -> list:
                             r"(?:condition|body)=%?([\w.\-]+)", line),
                         "operands": re.findall(r"%([\w.\-]+)", args),
                         "root": line.lstrip().startswith("ROOT "),
-                        "index": int(index.group(1)) if index else None})
+                        "index": int(index.group(1)) if index else None,
+                        "trips": int(trips.group(1)) if trips else None})
     fused = {c for i in out if i["opcode"] == "fusion" for c in i["calls"]}
     for i in out:
         i["fused"] = i["comp"] in fused
@@ -123,3 +126,47 @@ def microbatch_loop(ins) -> Loop:
     stale_mean = [i for i in body if i["opcode"] == "get-tuple-element"
                   and i["operands"] == param and i["index"] in stale]
     return Loop(body, root, readers, stale_mean)
+
+
+def runs(ins) -> dict:
+    """How many times each computation runs per run of the entry: a loop's
+    body runs its known trip count times per run of the loop, every other
+    called computation once per run of its caller."""
+    entry = {i["comp"] for i in ins if i["entry"]}
+    callers = collections.defaultdict(list)
+    for i in ins:
+        for c in i["calls"]:
+            callers[c].append((i["comp"], 1))
+        for c in i["loops"]:
+            assert i["trips"] is not None, i["name"]
+            callers[c].append((i["comp"], i["trips"]))
+    memo = {}
+
+    def count(comp):
+        if comp not in memo:
+            memo[comp] = 1 if comp in entry else sum(
+                count(p) * k for p, k in callers[comp])
+        return memo[comp]
+    return {i["comp"]: count(i["comp"]) for i in ins}
+
+
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def collectives(hlo: str) -> list:
+    """The program's collectives: each one's opcode, result dtype and dims
+    (None for a tuple), bytes and how many times it runs per run of the
+    program."""
+    ins = instructions(hlo)
+    n = runs(ins)
+    out = []
+    for i in ins:
+        if i["opcode"] in COLLECTIVES:
+            dtype, dims = (shape_dims(i["shape"])
+                           if not i["shape"].startswith("(") else (None, None))
+            out.append({"opcode": i["opcode"], "dtype": dtype,
+                        "dims": None if dims is None else list(dims),
+                        "bytes": hlo_bytes(i["shape"]),
+                        "runs": n[i["comp"]]})
+    return out

@@ -24,13 +24,14 @@ ALWEISS_KEY = 7
 STEP_DIM = 16   # full-gradient dim for the grab_step_workers check
 STEP_SKETCH = 8
 STEP_T = 4      # timesteps (2 pair steps)
-# cd-grab dry-run cell (SMOKE config on this worker's real n_dev x 1 mesh):
-# the sharding hillclimb + the analytic-vs-HLO sign-collective cross-check.
-DRYRUN_ARCH = "minicpm-2b"
-DRYRUN_SHAPE = "train_smoke"
-DRYRUN_SKETCH = 96   # no SMOKE param slab is [W, 96]-shaped -> unambiguous
-#                      fingerprint for the [W, k] sign all-gather isolation
-
+# the live mesh step (launch.live.build_live_step, as the training loop
+# builds it): phi3-mini's SMOKE config, W workers over the data axis, T = 2
+# timesteps a step. No SMOKE param is [W, K]-shaped, so the sign rows'
+# all-gathers are told apart by shape.
+LIVE_ARCH = "phi3-mini-3.8b"
+LIVE_T = 2
+LIVE_MICRO = 2
+LIVE_SEQ = 32
 
 def _inputs():
     import numpy as np
@@ -179,37 +180,52 @@ def main(n_dev: int) -> dict:
     out["deferred_replicated"] = bool(replicated_identically(eps_def)
                                       and replicated_identically(s_def))
 
-    # --- cd-grab dry-run cell: constraint hillclimb + analytic-vs-HLO ----
-    # Imported only now: jax is already initialized, so the module-level
-    # forced-device-count flag append in launch.dryrun is inert.
-    from jax.sharding import Mesh
-    from repro.launch.dryrun import run_cell
-
-    cell_mesh = Mesh(np.asarray(jax.devices()).reshape(n_dev, 1),
-                     ("data", "model"))
-    rec = run_cell(DRYRUN_ARCH, DRYRUN_SHAPE, cell_mesh, ordering="cd-grab",
-                   sketch_dim=DRYRUN_SKETCH, smoke=True, verbose=False)
-    out["dryrun"] = {k: rec.get(k) for k in (
-        "status", "reason",
-        "sign_collective_bytes_per_dev", "sign_collective_count",
-        "sign_collective_s",
-        "sign_collective_bytes_per_dev_hlo", "sign_collective_count_hlo",
-        "sign_collective_s_hlo", "sign_collective_delta")}
-    out["dryrun"]["cd_grab"] = rec.get("cd_grab")
-
-    # --- int8 dry-run cell: compressed-wire collective attribution --------
-    # constraints pinned to "slab" (skips the hillclimb re-run; the sign
-    # collective bytes don't depend on the constraint set anyway) so the
-    # parent can check bytes ratio vs the f32 cell + analytic-vs-HLO delta.
-    rec8 = run_cell(DRYRUN_ARCH, DRYRUN_SHAPE, cell_mesh, ordering="cd-grab",
-                    sketch_dim=DRYRUN_SKETCH, smoke=True, verbose=False,
-                    cd_constraints="slab", sign_wire="int8")
-    out["dryrun_int8"] = {k: rec8.get(k) for k in (
-        "status", "reason",
-        "sign_collective_bytes_per_dev", "sign_collective_count",
-        "sign_collective_bytes_per_dev_hlo", "sign_collective_count_hlo",
-        "sign_collective_delta")}
+    # --- the live mesh step's collectives, from its compiled HLO -------
+    import _hlo
+    out["live"] = {
+        name: _hlo.collectives(_live_step_hlo(wire, slab))
+        for name, wire, slab in (("int8", "int8", True), ("f32", "f32", True),
+                                 ("int8_unpinned", "int8", False))}
     return out
+
+
+def _live_step_hlo(wire: str, slab: bool) -> str:
+    """The optimized HLO text of ``build_live_step``'s jitted step on a
+    mesh of every device over ``data``, with the ``wire`` sign wire, the
+    deterministic balancer and sketch width K. ``slab=False`` builds it
+    with no explicit constraint (``CdGrabConstraints()``: XLA's own
+    propagation) in place of ``make_cd_constraints``'s slab set."""
+    import contextlib
+    from unittest import mock
+
+    import numpy as np
+    import jax
+
+    from repro.configs import get_config
+    from repro.core.grab import GrabConfig, make_sketch
+    from repro.launch import live
+    from repro.launch.mesh import make_elastic_mesh
+    from repro.models import lm
+    from repro.optim import adamw, constant
+    from repro.train.step import CdGrabConstraints
+
+    model = get_config(LIVE_ARCH)[1]
+    params = lm.init_lm(jax.random.PRNGKey(0), model)
+    grab_cfg = GrabConfig(pair_balance=True, sketch_dim=K, sign_wire=wire)
+    n_micro = LIVE_T * W
+    shape = (n_micro, LIVE_MICRO, LIVE_SEQ)
+    batch = {"tokens": np.zeros(shape, np.int32),
+             "labels": np.zeros(shape, np.int32)}
+    with (contextlib.nullcontext() if slab else mock.patch.object(
+            live, "make_cd_constraints",
+            lambda *a, **kw: CdGrabConstraints())):
+        step, state = live.build_live_step(
+            lambda p, mb: lm.loss_fn(p, model, mb, remat=True), adamw(),
+            constant(1e-3), grab_cfg, mesh=make_elastic_mesh(1),
+            params=params, batch_template=batch, n_micro=n_micro,
+            n_micro_total=4 * n_micro, n_workers=W,
+            sketch=make_sketch(params, K))
+    return step.lower(state, batch).compile().as_text()
 
 
 if __name__ == "__main__":

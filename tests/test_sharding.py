@@ -1,5 +1,5 @@
-"""Sharding rules + a miniature multi-device dry-run (subprocess with 8 fake
-CPU devices — the 512-device production sweep lives in launch/dryrun.py)."""
+"""Sharding rules + a miniature multi-device compile (subprocess with 8 fake
+CPU devices)."""
 import json
 import os
 import subprocess

@@ -237,6 +237,30 @@ def test_mesh_loop_state_sharded_and_caller_params_kept():
     assert not any(x.is_deleted() for x in jax.tree.leaves(params))
 
 
+def test_train_reaches_launch_only_through_the_mesh_step():
+    """``repro.train`` imports nothing of ``repro.launch`` at module level;
+    inside a function it imports ``launch.live`` (the mesh step) alone."""
+    import ast
+    import pathlib
+
+    import repro.train
+
+    found = set()
+    for f in pathlib.Path(repro.train.__file__).parent.glob("*.py"):
+        tree = ast.parse(f.read_text())
+        top = set(map(id, tree.body))
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                names = [node.module or ""]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            found |= {(f.name, n, id(node) in top) for n in names
+                      if n.startswith("repro.launch")}
+    assert found == {("loop.py", "repro.launch.live", False)}, found
+
+
 def test_loop_records_compiled_step_bytes():
     """The loop records the compiled step's device bytes as gauges, and the
     first call reuses that compile: the step is traced once."""
